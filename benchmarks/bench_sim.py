@@ -572,6 +572,9 @@ def main(argv=None) -> int:
                     help="output JSON path (default: repo-root BENCH_sim.json)")
     args = ap.parse_args(argv)
 
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from repro.analysis import repo_is_clean
 
     reps = 1 if args.quick else 9

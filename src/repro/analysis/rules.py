@@ -478,7 +478,7 @@ _JAX_EIG = {"jax.numpy.linalg.eig", "jax.numpy.linalg.eigvals",
 
 def rule_dtype002_eig_needs_x64(mod: ModuleInfo) -> list[Finding]:
     """Jax eigensolves must sit lexically inside a ``with
-    jax.experimental.enable_x64():`` block: jax defaults to f32, so
+    jax.enable_x64(True):`` block: jax defaults to f32, so
     ``jnp.linalg.eig*`` on a float64 capacity/W matrix silently downgrades
     and the paper's lambda loses ~4 digits against the numpy plane (the
     ``rate_opt`` ``backend="jax"`` bug). The scope must be lexical — tracing
@@ -490,7 +490,7 @@ def rule_dtype002_eig_needs_x64(mod: ModuleInfo) -> list[Finding]:
             continue
         if any(isinstance(item.context_expr, ast.Call)
                and ctx.canon(item.context_expr.func)
-               == "jax.experimental.enable_x64"
+               == "jax.enable_x64"
                for item in node.items):
             covered.update(id(n) for n in ast.walk(node))
     out = []
@@ -502,7 +502,7 @@ def rule_dtype002_eig_needs_x64(mod: ModuleInfo) -> list[Finding]:
                 f"`{name[10:]}` outside an `enable_x64()` scope - jax "
                 "eigensolves run f32 by default and silently downgrade the "
                 "spectral lambda; wrap the traced region in "
-                "`with jax.experimental.enable_x64():`"))
+                "`with jax.enable_x64(True):`"))
     return out
 
 
@@ -655,7 +655,7 @@ RULE_CATALOG = {
               "code",
     "JIT003": "Python round/node loop in a module advertising jitted paths",
     "DTYPE001": "float64 flowing into jax arrays",
-    "DTYPE002": "jnp.linalg.eig* outside a jax.experimental.enable_x64 "
+    "DTYPE002": "jnp.linalg.eig* outside a jax.enable_x64 "
                 "scope",
     "PAL001": "Pallas interpret-mode not routed through _default_interpret",
     "PAL002": "sub-fp32 accumulation inside a Pallas kernel body",

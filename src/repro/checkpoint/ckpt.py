@@ -153,6 +153,10 @@ def compact_nodes(state: PyTree, live: np.ndarray) -> PyTree:
             f"state node axis is {width} but live mask has {live.size} "
             "entries")
     idx = np.flatnonzero(live)
+    if idx.size == width:
+        # every node live: a gather would only copy (and, on a mesh, may
+        # gather the sharded node axis onto every device)
+        return state
     return jax.tree.map(
         lambda leaf: leaf if leaf.ndim == 0 else leaf[idx], state)
 

@@ -78,11 +78,18 @@ def replicate(params: PyTree, n: int) -> PyTree:
     return jax.tree.map(lambda p: jnp.broadcast_to(p[None], (n, *p.shape)), params)
 
 
+# Mixing matmuls run at full precision: the TPU's default f32 matmul rounds
+# its operands to bf16, which would cut every parameter to bf16 each round
+# (an identity W row would not return its node's parameters verbatim).
+_MIX_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def mix(node_params: PyTree, w: jax.Array) -> PyTree:
     """X <- W @ X on the leading node axis of every leaf."""
     def _mix(leaf: jax.Array) -> jax.Array:
         flat = leaf.reshape(leaf.shape[0], -1)
-        return (w.astype(flat.dtype) @ flat).reshape(leaf.shape)
+        return jnp.matmul(w.astype(flat.dtype), flat,
+                          precision=_MIX_PRECISION).reshape(leaf.shape)
     return jax.tree.map(_mix, node_params)
 
 
@@ -290,7 +297,8 @@ def _mix_compressed_message(
     w32 = w.astype(jnp.float32)
     diag = jnp.diagonal(w32)
     off = w32 - jnp.diag(diag)
-    mixed = diag[:, None] * flat + off @ deq
+    mixed = diag[:, None] * flat + jnp.matmul(off, deq,
+                                              precision=_MIX_PRECISION)
 
     out, res_out, offset = [], [], 0
     for p in leaves:
@@ -335,7 +343,8 @@ def _mix_compressed_leaf(
             raise ValueError(f"unknown compression mode {quant.mode!r}")
         new_res = carried - deq if quant.error_feedback else res
         new_res = jnp.where(live_col, new_res, jnp.zeros((), new_res.dtype))
-        mixed = diag[:, None] * flat + off @ deq
+        mixed = diag[:, None] * flat + jnp.matmul(off, deq,
+                                              precision=_MIX_PRECISION)
         return mixed.reshape(p.shape).astype(p.dtype), new_res.reshape(p.shape)
 
     leaves, treedef = jax.tree.flatten(node_params)

@@ -182,31 +182,28 @@ _JAX_LAM_FN = None
 
 def _spectral_lambda_batch_jax(w: np.ndarray) -> np.ndarray:
     """vmap+jit eigenvalue pass for large batches, run under a **local x64
-    scope** (``jax.experimental.enable_x64``) so the eigensolve really is
-    float64: without it jax silently truncates the float64 candidate stack
-    to f32 and the trailing ``asarray(..., float64)`` cast only launders the
+    scope** (``jax.enable_x64``) so the eigensolve really is float64:
+    without it jax silently truncates the float64 candidate stack to f32
+    and the trailing ``asarray(..., float64)`` cast only launders the
     low-precision result. Still approximate relative to the numpy path
     (different eig kernels — LAPACK via XLA vs LAPACK via numpy — agreement
-    is pinned to ~1e-9 in tests/test_scale.py, not bit-exact); asymmetric
-    eig is CPU-only in jax, so failures fall back to numpy."""
+    is pinned to ~1e-9 in tests/test_scale.py, not bit-exact). Asymmetric
+    eig is CPU-only in jax: on any other backend the call raises with
+    jax's own error rather than quietly running numpy."""
     global _JAX_LAM_FN
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
+    import jax
+    import jax.numpy as jnp
 
-        with enable_x64():
-            if _JAX_LAM_FN is None:
-                def _one(m):
-                    e = jnp.linalg.eigvals(m)
-                    mags = jnp.abs(e)
-                    drop = jnp.argmin(jnp.abs(e - 1.0))
-                    return jnp.max(mags.at[drop].set(-jnp.inf))
+    with jax.enable_x64(True):
+        if _JAX_LAM_FN is None:
+            def _one(m):
+                e = jnp.linalg.eigvals(m)
+                mags = jnp.abs(e)
+                drop = jnp.argmin(jnp.abs(e - 1.0))
+                return jnp.max(mags.at[drop].set(-jnp.inf))
 
-                _JAX_LAM_FN = jax.jit(jax.vmap(_one))
-            return np.asarray(_JAX_LAM_FN(w), dtype=np.float64)
-    except Exception:
-        return spectral_lambda_batch(w)
+            _JAX_LAM_FN = jax.jit(jax.vmap(_one))
+        return np.asarray(_JAX_LAM_FN(w), dtype=np.float64)
 
 
 def evaluate_rates_batch(

@@ -19,9 +19,7 @@ def _default_interpret() -> bool:
     """True unless the **current** ``jax.default_backend()`` is TPU.
 
     Evaluated per call — it is one cached jax lookup — so a backend
-    attached after the first call changes the answer.
+    attached after the first call changes the answer. A failing backend
+    probe raises: falling back to interpret mode would hide the device.
     """
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    return jax.default_backend() != "tpu"

@@ -17,7 +17,10 @@ Two payload layouts:
 
 Tiling: buffers are viewed as (K, N); each grid step owns an (K, bn) tile
 with bn = 8*128*8 lanes (VPU-aligned, fp32). K = degree+1 <= 9 is static and
-unrolled. Accumulation is fp32 regardless of payload dtype.
+unrolled. Accumulation is fp32 regardless of payload dtype. The int8 path
+views its lanes as (rows, 128) tiles and hands each tile its four scales
+in one padded 128-lane row per payload, so every block's last two dims are
+(8, 128)-aligned or whole, as the TPU lowering requires.
 
 Execution mode: ``interpret=None`` (the default) auto-selects per call —
 compiled Pallas when the **current** ``jax.default_backend()`` is TPU,
@@ -40,6 +43,7 @@ __all__ = ["gossip_mix", "gossip_mix_q8"]
 
 _BN = 8 * 128 * 8   # lanes per tile (fp32 VPU tile x 8 rows)
 _SB = 2048          # int8 scale-block lanes (== core.compression._BLOCK)
+_LANES = 128        # TPU vector lanes
 
 
 def _kernel(w_ref, b_ref, o_ref):
@@ -84,13 +88,22 @@ def gossip_mix(bufs: jax.Array, weights: jax.Array,
 
 
 def _q8_kernel(w_ref, x_ref, q_ref, s_ref, o_ref):
+    """One (rows, 128) lane tile: ``s_ref`` holds this tile's scales in the
+    first ``_BN // _SB`` lanes of one 128-lane row per payload."""
     k = q_ref.shape[0]
-    acc = w_ref[0] * x_ref[...].astype(jnp.float32)      # exact self term
-    for i in range(k):  # static unroll, dequantize on the VMEM tile
-        deq = (q_ref[i, :].astype(jnp.float32).reshape(-1, _SB)
-               * s_ref[i, :][:, None])
-        acc = acc + w_ref[i + 1] * deq.reshape(-1)
-    o_ref[...] = acc
+    rows = _SB // _LANES                           # rows per scale block
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    x = x_ref[...]
+    deq = [q_ref[i].astype(jnp.float32) for i in range(k)]
+    s = s_ref[0]                                   # (K, 128)
+    for b in range(_BN // _SB):  # static unroll, dequantize on the VMEM tile
+        sl = slice(b * rows, (b + 1) * rows)
+        acc = w_ref[0] * x[sl]                     # exact self term
+        for i in range(k):
+            scale = jnp.sum(jnp.where(lane == b, s[i:i + 1], 0.0), axis=1,
+                            keepdims=True)         # (1, 1) lane pick
+            acc = acc + w_ref[i + 1] * (deq[i][sl] * scale)
+        o_ref[sl, :] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -98,28 +111,37 @@ def _gossip_mix_q8(self_buf, q_bufs, scales, weights, interpret):
     n = self_buf.shape[0]
     k, np8 = q_bufs.shape
     np_ = n + (-n) % _BN                     # tile-aligned lane count
+    tiles, per_tile = np_ // _BN, _BN // _SB
+    # every operand becomes (rows, 128) lanes; int8 payloads arrive as whole
+    # 2048-lane blocks, padded (with one scale per padded block) out to the
+    # tile width — zero lanes contribute exact zeros whatever the pad scale
     x = jnp.pad(self_buf.astype(jnp.float32), (0, np_ - n))
-    # int8 payloads arrive as whole 2048-lane blocks; pad them (and one
-    # scale per padded block) out to the tile width — zero lanes contribute
-    # exact zeros whatever the pad scale
-    pad8 = max(np_ - np8, 0)
-    q = jnp.pad(q_bufs, ((0, 0), (0, pad8)))
-    s = jnp.pad(scales, ((0, 0), (0, pad8 // _SB)), constant_values=1.0)
-    grid = (np_ // _BN,)
+    q = jnp.pad(q_bufs, ((0, 0), (0, np_ - np8)))
+    s = jnp.pad(scales.astype(jnp.float32),
+                ((0, 0), (0, tiles * per_tile - scales.shape[1])),
+                constant_values=1.0)
+    # scales per tile as one padded 128-lane row per payload: the block's
+    # last two dims (K, 128) span K and are lane-aligned, as the TPU
+    # lowering requires
+    s = jnp.pad(s.reshape(k, tiles, per_tile).transpose(1, 0, 2),
+                ((0, 0), (0, 0), (0, _LANES - per_tile)),
+                constant_values=1.0)
+    tile_rows = _BN // _LANES
     out = pl.pallas_call(
         _q8_kernel,
-        grid=grid,
+        grid=(tiles,),
         in_specs=[
-            pl.BlockSpec((k + 1,), lambda i: (0,)),           # self + K weights
-            pl.BlockSpec((_BN,), lambda i: (i,)),             # exact self tile
-            pl.BlockSpec((k, _BN), lambda i: (0, i)),         # int8 tiles
-            pl.BlockSpec((k, _BN // _SB), lambda i: (0, i)),  # per-block scales
+            pl.BlockSpec((k + 1,), lambda i: (0,)),        # self + K weights
+            pl.BlockSpec((tile_rows, _LANES), lambda i: (i, 0)),       # self
+            pl.BlockSpec((k, tile_rows, _LANES), lambda i: (0, i, 0)),  # q8
+            pl.BlockSpec((1, k, _LANES), lambda i: (i, 0, 0)),       # scales
         ],
-        out_specs=pl.BlockSpec((_BN,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
+        out_specs=pl.BlockSpec((tile_rows, _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((np_ // _LANES, _LANES), jnp.float32),
         interpret=interpret,
-    )(weights.astype(jnp.float32), x, q[:, :np_], s[:, :np_ // _SB])
-    return out[:n]
+    )(weights.astype(jnp.float32), x.reshape(-1, _LANES),
+      q.reshape(k, -1, _LANES), s)
+    return out.reshape(-1)[:n]
 
 
 def gossip_mix_q8(self_buf: jax.Array, q_bufs: jax.Array, scales: jax.Array,

@@ -25,46 +25,69 @@ __all__ = ["quantize_int8", "dequantize_int8"]
 
 _BLOCK = 256     # lanes per scale block (multiple of 128)
 _ROWS = 8        # rows per tile
+_LANES = 128     # TPU vector lanes: one padded scale row per tile
+_MAX_BLOCKS = 16  # scale blocks per column tile (<= _LANES)
+
+
+def _col_tile(c: int) -> int:
+    """Lanes per column tile: the most whole scale blocks (at most
+    ``_MAX_BLOCKS``) that divide ``c``, so the grid covers every column."""
+    nb = c // _BLOCK
+    return _BLOCK * max(d for d in range(1, min(nb, _MAX_BLOCKS) + 1)
+                        if nb % d == 0)
 
 
 def _q_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)               # (rows, cols)
-    rows, cols = x.shape
-    xb = x.reshape(rows, cols // _BLOCK, _BLOCK)
-    scale = jnp.max(jnp.abs(xb), axis=-1) / 127.0
-    scale = jnp.where(scale == 0, 1.0, scale)
-    q = jnp.clip(jnp.round(xb / scale[..., None]), -127, 127)
-    q_ref[...] = q.reshape(rows, cols).astype(jnp.int8)
-    s_ref[...] = scale
+    rows, cols = x_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    s_tile = jnp.ones((rows, _LANES), jnp.float32)
+    for b in range(cols // _BLOCK):  # static unroll over the tile's blocks
+        sl = slice(b * _BLOCK, (b + 1) * _BLOCK)
+        xb = x_ref[:, sl].astype(jnp.float32)
+        scale = jnp.max(jnp.abs(xb), axis=-1, keepdims=True) / 127.0
+        scale = jnp.where(scale == 0, 1.0, scale)
+        q_ref[:, sl] = jnp.clip(jnp.round(xb / scale), -127,
+                                127).astype(jnp.int8)
+        s_tile = jnp.where(lane == b, scale, s_tile)   # scale -> lane b
+    s_ref[...] = s_tile
 
 
 def _dq_kernel(q_ref, s_ref, o_ref):
     rows, cols = q_ref.shape
-    qb = q_ref[...].astype(jnp.float32).reshape(rows, cols // _BLOCK, _BLOCK)
-    o_ref[...] = (qb * s_ref[...][..., None]).reshape(rows, cols).astype(o_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    s_tile = s_ref[...]
+    for b in range(cols // _BLOCK):
+        sl = slice(b * _BLOCK, (b + 1) * _BLOCK)
+        scale = jnp.sum(jnp.where(lane == b, s_tile, 0.0), axis=-1,
+                        keepdims=True)                 # lane b -> (rows, 1)
+        o_ref[:, sl] = (q_ref[:, sl].astype(jnp.float32)
+                        * scale).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _quantize_int8(x: jax.Array, interpret: bool
                    ) -> tuple[jax.Array, jax.Array]:
     r, c = x.shape
-    bc = min(c, _BLOCK * 16)
+    bc = _col_tile(c)
     grid = (r // _ROWS, c // bc)
+    # scales leave the kernel as one padded 128-lane row per tile, so the
+    # scale block (_ROWS, 128) is aligned as the TPU lowering requires
     q, s = pl.pallas_call(
         _q_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((_ROWS, bc), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((_ROWS, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((_ROWS, bc // _BLOCK), lambda i, j: (i, j)),
+            pl.BlockSpec((_ROWS, _LANES), lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((r, c), jnp.int8),
-            jax.ShapeDtypeStruct((r, c // _BLOCK), jnp.float32),
+            jax.ShapeDtypeStruct((r, grid[1] * _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(x)
-    return q, s
+    s = s.reshape(r, grid[1], _LANES)[:, :, :bc // _BLOCK]
+    return q, s.reshape(r, c // _BLOCK)
 
 
 def quantize_int8(x: jax.Array, interpret: bool | None = None
@@ -80,19 +103,22 @@ def quantize_int8(x: jax.Array, interpret: bool | None = None
 def _dequantize_int8(q: jax.Array, s: jax.Array, dtype,
                      interpret: bool) -> jax.Array:
     r, c = q.shape
-    bc = min(c, _BLOCK * 16)
+    bc = _col_tile(c)
     grid = (r // _ROWS, c // bc)
+    per_tile = bc // _BLOCK
+    s = jnp.pad(s.astype(jnp.float32).reshape(r, grid[1], per_tile),
+                ((0, 0), (0, 0), (0, _LANES - per_tile)))
     return pl.pallas_call(
         _dq_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((_ROWS, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((_ROWS, bc // _BLOCK), lambda i, j: (i, j)),
+            pl.BlockSpec((_ROWS, _LANES), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((_ROWS, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), dtype),
         interpret=interpret,
-    )(q, s)
+    )(q, s.reshape(r, grid[1] * _LANES))
 
 
 def dequantize_int8(q: jax.Array, s: jax.Array, dtype=jnp.float32,

@@ -45,9 +45,8 @@ def make_fleet_mesh(fleet: int = 2, model: int = 2):
             f"only {avail} are visible (set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N before "
             "importing jax)")
-    axt = getattr(jax.sharding, "AxisType", None)  # jax >= 0.5 only
-    kw = {"axis_types": (axt.Auto,) * 2} if axt is not None else {}
-    return jax.make_mesh((fleet, model), ("fleet", "model"), **kw)
+    return jax.make_mesh((fleet, model), ("fleet", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def replica_axes(mesh) -> tuple[str, ...]:

@@ -38,7 +38,8 @@ import numpy as np
 
 from ..core.compression import QuantConfig
 from ..core.dpsgd import (DPSGDConfig, dpsgd_masked_compressed_step,
-                          dpsgd_masked_step, node_axis_size, zero_residuals)
+                          dpsgd_masked_step, node_axis_size, replicate,
+                          zero_residuals)
 from .scenario import ScenarioConfig, get_scenario
 from .trace import (TraceBatch, TrainTrace, driver_batch_indices,
                     model_batch_tokens, precompute_traces)
@@ -81,7 +82,7 @@ def _row_where(mask: jax.Array, a: PyTree, b: PyTree) -> PyTree:
 
 
 @partial(jax.jit,
-         static_argnames=("loss_fn", "config", "collect_node0", "unroll",
+         static_argnames=("loss_fn", "config", "snapshot_rounds", "unroll",
                           "payload", "watchdog"))
 def train_on_trace(
     loss_fn: Callable[[PyTree, PyTree], Any],
@@ -90,7 +91,7 @@ def train_on_trace(
     live_seq,
     batch_seq: PyTree,
     config: DPSGDConfig = DPSGDConfig(),
-    collect_node0: bool = False,
+    snapshot_rounds: tuple[int, ...] = (),
     unroll: int | bool = True,
     payload: QuantConfig = _NO_PAYLOAD,
     active_seq=None,
@@ -103,11 +104,12 @@ def train_on_trace(
     per-node minibatches (dead rows may hold arbitrary filler — their
     gradients are masked off). Returns ``(final_params, losses)`` with
     ``losses`` (rounds, n) raw per-node losses (mask with ``live_seq``
-    before aggregating), plus per-round snapshots of the first live node's
-    parameters when ``collect_node0`` (for post-hoc accuracy curves). The
-    snapshot stack costs O(rounds x |node params|) device memory — fine for
-    paper-scale models; disable it (and evaluate from ``final_params``)
-    when that bill matters.
+    before aggregating), plus a (K, ...) stack of the first live node's
+    parameters after each of the K rounds in ``snapshot_rounds`` (for
+    post-hoc accuracy curves) when that tuple is non-empty. The stack rides
+    the scan carry with one spare slot that absorbs the other rounds'
+    writes, so it costs (K + 1) x |node params| of device memory, not
+    rounds x |node params|.
 
     ``unroll`` is forwarded to ``lax.scan``. The default (full unroll)
     trades one longer compile for straight-line round code — on XLA:CPU the
@@ -125,8 +127,8 @@ def train_on_trace(
     ``active_seq`` (rounds, n), when given, is the gradient mask instead of
     ``live_seq`` — the fault plane's "live but crashed this round" nodes
     keep stale parameters (identity W rows) without taking a local step,
-    while ``live_seq`` still decides whose parameters the ``collect_node0``
-    snapshot tracks (the first *churn*-live node, matching the per-round
+    while ``live_seq`` still decides whose parameters the snapshots
+    track (the first *churn*-live node, matching the per-round
     driver's row 0 regardless of transient crashes).
 
     ``watchdog`` arms a per-node convergence guard inside the scan: after
@@ -141,9 +143,16 @@ def train_on_trace(
             "resolved by the joint planner at simulation time — train with "
             "the mode the plan actually picked")
     compressed = payload.mode != "none"
+    n_snap = len(snapshot_rounds)
+    # snapshot slot per round: its index in snapshot_rounds, else the spare
+    # slot n_snap
+    slot_of = {r: k for k, r in enumerate(snapshot_rounds)}
+    slots = [slot_of.get(r, n_snap) for r in range(live_seq.shape[0])]
 
     def body(carry, xs):
-        w, live, active, batch = xs
+        w, live, active, batch, slot = xs
+        if n_snap:
+            carry, snaps = carry
         if watchdog:
             inner, good = carry
         else:
@@ -165,10 +174,13 @@ def train_on_trace(
         new_carry = (new_params, new_res) if compressed else new_params
         if watchdog:
             new_carry = (new_carry, good)
+        if n_snap:
+            first = jnp.argmax(live)   # first live row (original-id order)
+            snaps = jax.tree.map(
+                lambda b, p: jax.lax.dynamic_update_index_in_dim(
+                    b, p[first], slot, 0), snaps, new_params)
+            new_carry = (new_carry, snaps)
         outs = (losses,)
-        if collect_node0:
-            first = jnp.argmax(live)        # first live row (original-id order)
-            outs = outs + (jax.tree.map(lambda p: p[first], new_params),)
         if watchdog:
             outs = outs + (bad,)
         return new_carry, outs
@@ -180,9 +192,18 @@ def train_on_trace(
               else node_params)
     if watchdog:
         carry0 = (carry0, node_params)
-    final, outs = jax.lax.scan(body, carry0,
-                               (w_seq, live_seq, grad_mask, batch_seq),
-                               unroll=unroll)
+    if n_snap:
+        carry0 = (carry0, jax.tree.map(
+            lambda p: jnp.zeros((n_snap + 1,) + p.shape[1:], p.dtype),
+            node_params))
+    final, outs = jax.lax.scan(
+        body, carry0, (w_seq, live_seq, grad_mask, batch_seq,
+                       jnp.asarray(slots, jnp.int32) if n_snap else None),
+        unroll=unroll)
+    if n_snap:
+        final, snaps = final
+        outs = outs[:1] + (jax.tree.map(lambda b: b[:n_snap], snaps),) \
+            + outs[1:]
     if watchdog:
         final = final[0]
     if compressed:
@@ -198,7 +219,7 @@ def train_on_traces(
     live_seq,
     batch_seq: PyTree,
     config: DPSGDConfig = DPSGDConfig(),
-    collect_node0: bool = False,
+    snapshot_rounds: tuple[int, ...] = (),
     params_batched: bool = False,
     unroll: int | bool = True,
     payload: QuantConfig = _NO_PAYLOAD,
@@ -215,15 +236,15 @@ def train_on_traces(
     if active_seq is None:
         def one(p, w, live, b):
             return train_on_trace(loss_fn, p, w, live, b, config,
-                                  collect_node0, unroll, payload,
+                                  snapshot_rounds, unroll, payload,
                                   watchdog=watchdog)
         axes = (0 if params_batched else None, 0, 0, 0)
         return jax.vmap(one, in_axes=axes)(
             node_params, w_seq, live_seq, batch_seq)
 
     def one(p, w, live, act, b):
-        return train_on_trace(loss_fn, p, w, live, b, config, collect_node0,
-                              unroll, payload, active_seq=act,
+        return train_on_trace(loss_fn, p, w, live, b, config,
+                              snapshot_rounds, unroll, payload, active_seq=act,
                               watchdog=watchdog)
 
     axes = (0 if params_batched else None, 0, 0, 0, 0)
@@ -431,24 +452,35 @@ def transformer_adapter(arch: str = "stablelm-3b", batch: int = 4,
         param_shapes=leaf_shapes)
 
 
-def _shard_family(params0: PyTree, batches: PyTree, mesh):
+def _replicate_family(inits: PyTree, n_nodes: int) -> PyTree:
+    """(S, ...) per-trace inits -> (S, n, ...): every node of a trace
+    starts from that trace's x_0."""
+    return jax.vmap(lambda p: replicate(p, n_nodes))(inits)
+
+
+def _shard_family(inits: PyTree, n_nodes: int, batches: PyTree, mesh):
     """Lay the (S,)-batched family out on ``mesh``: node-parameters take
     ``train.shardings.node_param_specs`` with the Monte-Carlo axis
     replicated in front (P(None, fleet..., tp-rules...)); batch leaves
     shard their node axis (dim 2 of (S, rounds, n, ...)) over the fleet
     axes when divisible. The jitted scan/vmap then runs with the carry
-    sharded — no gather of the model onto one device."""
+    sharded — no gather of the model onto one device. The node axis is
+    broadcast inside a jit straight into its sharding, so no device ever
+    holds the whole (S, n, ...) stack."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..train.shardings import node_param_specs
 
-    one = jax.tree.map(lambda x: x[0], params0)
-    specs = node_param_specs(one, mesh)
-    p_leaves, tdef = jax.tree.flatten(params0)
+    one = jax.eval_shape(lambda i: _replicate_family(i, n_nodes), inits)
+    specs = node_param_specs(
+        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                     one), mesh)
     s_leaves = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
-    params0 = jax.tree.unflatten(tdef, [
-        jax.device_put(x, NamedSharding(mesh, P(None, *tuple(s))))
-        for x, s in zip(p_leaves, s_leaves)])
+    shardings = jax.tree.unflatten(
+        jax.tree.structure(one),
+        [NamedSharding(mesh, P(None, *tuple(s))) for s in s_leaves])
+    params0 = jax.jit(_replicate_family, static_argnums=1,
+                      out_shardings=shardings)(inits, n_nodes)
 
     node_axes = tuple(a for a in mesh.axis_names if a != "model")
     fleet = int(np.prod([mesh.shape[a] for a in node_axes], dtype=np.int64))
@@ -498,7 +530,6 @@ def train_model_on_traces(
     ``eval_fn``), ``curves``, per-trace compacted ``final_params``, and
     watchdog ``rollbacks``."""
     from ..checkpoint.ckpt import compact_nodes
-    from ..core import dpsgd
 
     cfgs = [get_scenario(c) if isinstance(c, str) else c for c in configs]
     if not cfgs:
@@ -546,23 +577,29 @@ def train_model_on_traces(
 
     built = [adapter.batch_fn(c, t) for c, t in zip(cfgs, traces.traces)]
     batches = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *built)
-    params0 = [dpsgd.replicate(adapter.init_params(c.seed), n_nodes)
-               for c in cfgs]
-    params0 = jax.tree.map(lambda *xs: jnp.stack(xs), *params0)
+    inits = jax.tree.map(lambda *xs: jnp.stack(xs),
+                         *[adapter.init_params(c.seed) for c in cfgs])
     if mesh is not None:
-        params0, batches = _shard_family(params0, batches, mesh)
+        params0, batches = _shard_family(inits, n_nodes, batches, mesh)
+    else:
+        params0 = _replicate_family(inits, n_nodes)
+    del inits
 
+    eval_rounds = [r for r in range(n_rounds)
+                   if (r + 1) % eval_every == 0 or r + 1 == n_rounds]
+    # the last eval round is the final round: its snapshot is read from the
+    # final parameters, so the scan carries only the earlier ones
+    snapshot_rounds = (tuple(eval_rounds[:-1]) if adapter.eval_fn is not None
+                       else ())
     out_arrays = train_on_traces(
         adapter.loss_fn, params0,
         jnp.asarray(traces.w_eff), jnp.asarray(traces.live), batches,
-        DPSGDConfig(eta=eta), collect_node0=True, params_batched=True,
-        unroll=unroll, payload=payload,
+        DPSGDConfig(eta=eta), snapshot_rounds=snapshot_rounds,
+        params_batched=True, unroll=unroll, payload=payload,
         active_seq=jnp.asarray(traces.active), watchdog=watchdog)
-    if watchdog:
-        finals, losses, snaps, rollbacks = out_arrays
-    else:
-        finals, losses, snaps = out_arrays
-        rollbacks = None
+    finals, losses = out_arrays[:2]
+    snaps = out_arrays[2] if snapshot_rounds else None
+    rollbacks = out_arrays[-1] if watchdog else None
 
     live = traces.live                                    # (S, rounds, n)
     raw = np.asarray(losses, dtype=np.float64)            # (S, rounds, n)
@@ -570,13 +607,17 @@ def train_model_on_traces(
     masked = np.where(live, raw, 0.0)
     mean_losses = masked.sum(-1) / live.sum(-1)           # masked driver mean
 
-    eval_rounds = [r for r in range(n_rounds)
-                   if (r + 1) % eval_every == 0 or r + 1 == n_rounds]
     s_count = traces.n_traces
     if adapter.eval_fn is not None:
+        first = np.argmax(live[:, -1], axis=1)           # (S,) row at the end
+        last = jax.tree.map(lambda p: p[np.arange(s_count), first][:, None],
+                            finals)
+        if snaps is not None:
+            last = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=1),
+                                snaps, last)
         sel = jax.tree.map(
-            lambda p: p[:, np.asarray(eval_rounds)].reshape(
-                (s_count * len(eval_rounds),) + p.shape[2:]), snaps)
+            lambda p: p.reshape((s_count * len(eval_rounds),) + p.shape[2:]),
+            last)
         accs = jax.vmap(adapter.eval_fn)(sel)
         accs = np.asarray(accs, dtype=np.float64).reshape(
             s_count, len(eval_rounds))

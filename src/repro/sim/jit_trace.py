@@ -27,12 +27,16 @@ Numerics: the MAC semantics are ``mac.tdm_round``'s — every active node
 airs all packets in pass 0, retransmission passes resend packets any
 intended receiver still needs, a packet is decoded iff the instantaneous
 capacity carries its rate, and the clock advances packet by packet in
-float64 (the whole program is traced under ``jax.experimental.enable_x64``).
+float64 (the whole program is traced under ``jax.enable_x64``).
 On the static scenario the round time reproduces Eq. 3 / the event loop to
 relative float64 tolerance (the scan sums a transmitter's packet airtimes
 before adding them to the clock, so the association differs in the last
-bits). Under fading the Rayleigh gains come from a stateless splitmix64
-hash of ``(fading.seed, coherence block, unordered node pair)`` — per-block
+bits). The program returns who decoded whom; the host turns each round's
+delivered graph into W with the event loop's own Eq. 4 code, so W is
+bit-identical to the event loop's for the same deliveries on any backend
+(the TPU emulates float64 with fewer significant bits than IEEE binary64,
+and 1/3 computed there is not numpy's 1/3). Under fading the Rayleigh
+gains come from a stateless splitmix64 hash of ``(fading.seed, coherence block, unordered node pair)`` — per-block
 independent, reciprocal, Exp(1)-distributed, deterministic across runs and
 processes, but a *third* RNG scheme: realizations differ from the host
 MAC's ``chunked``/``per_block`` streams (identical in distribution, not in
@@ -123,18 +127,18 @@ def _rayleigh_gains(seed: int, blocks, i, n: int):
 @lru_cache(maxsize=32)
 def _round_scan(n: int, n_pkts: int, passes: int, fading_on: bool,
                 coherence_s: float, bandwidth_hz: float, overhead_s: float,
-                compute_s: float, degrade: str, seed: int, n_rounds: int):
+                compute_s: float, seed: int, n_rounds: int):
     """Build (and cache) the jitted trace program for one static shape.
 
-    The returned function maps ``(rates, sizes, recv, chan, planned_w)`` to
-    per-round ``(w_eff, t_start, t_comm, delivered, retx)`` stacks plus the
-    final clock. ``chan`` is the raw mean SNR matrix under fading, else the
-    precomputed static decode table ``capacity >= rate_i``.
+    The returned function maps ``(rates, sizes, recv, chan)`` to per-round
+    ``(t_start, t_comm, delivered, retx)`` stacks plus the final clock.
+    ``chan`` is the raw mean SNR matrix under fading, else the precomputed
+    static decode table ``capacity >= rate_i``.
     """
     import jax
     import jax.numpy as jnp
 
-    def run(rates, sizes, recv, chan, planned_w):
+    def run(rates, sizes, recv, chan):
         active = jnp.isfinite(rates) & (rates > 0)
         durs = (sizes[None, :] / jnp.where(active, rates, 1.0)[:, None]
                 + overhead_s)                                  # (n, P)
@@ -169,13 +173,7 @@ def _round_scan(n: int, n_pkts: int, passes: int, fading_on: bool,
             t_start = clock
             clock, (delivered, retx) = jax.lax.scan(tx_step, clock, idx)
             t_comm = clock - t_start
-            a = delivered.T * 1.0          # bool -> float64 under x64
-            a = a.at[idx, idx].set(1.0)
-            if degrade == "renorm":
-                w = a / a.sum(axis=1, keepdims=True)
-            else:                                              # "naive"
-                w = planned_w * a
-            return clock + compute_s, (w, t_start, t_comm, delivered,
+            return clock + compute_s, (t_start, t_comm, delivered,
                                        retx.sum())
 
         clock, outs = jax.lax.scan(round_step, jnp.asarray(0.0), None,
@@ -199,7 +197,7 @@ def precompute_trace_scan(cfg, n_rounds: int, sim=None, **overrides):
     (cfg)``) hand it over instead of planning twice; it must have been built
     from this exact ``cfg`` (no ``overrides`` then).
     """
-    from jax.experimental import enable_x64
+    import jax
 
     from .trace import RoundRecord, SimTrace, TrainTrace, WirelessSimulator
 
@@ -239,19 +237,25 @@ def precompute_trace_scan(cfg, n_rounds: int, sim=None, **overrides):
         chan = cap >= rates[:, None]
         coherence_s = 1.0
         seed = 0
-    planned = recv.T.astype(np.float64)
-    np.fill_diagonal(planned, 1.0)
-    planned_w = paper_w(planned)
-
     fn = _round_scan(n, int(sizes.size), 1 + int(cfg.mac.max_retx_rounds),
                      fading_on, coherence_s, float(cfg.bandwidth_hz),
                      float(cfg.mac.per_packet_overhead_s),
-                     float(cfg.compute_s_per_round), cfg.degrade, seed,
-                     int(n_rounds))
-    with enable_x64():
-        out = fn(rates, sizes, recv, chan, planned_w)
-        w_eff, t_start, t_comm, delivered, retx, t_end = \
+                     float(cfg.compute_s_per_round), seed, int(n_rounds))
+    with jax.enable_x64(True):
+        out = fn(rates, sizes, recv, chan)
+        t_start, t_comm, delivered, retx, t_end = \
             [np.asarray(x) for x in out]
+
+    # W from the delivered graph with the event loop's Eq. 4 code
+    # (``RoundResult.effective_w``), batched over rounds
+    a = delivered.transpose(0, 2, 1).astype(np.float64)  # a[j, i]: j got i
+    a[:, np.arange(n), np.arange(n)] = 1.0
+    if cfg.degrade == "renorm":
+        w_eff = paper_w(a)
+    else:                                                      # "naive"
+        planned = recv.T.astype(np.float64)
+        np.fill_diagonal(planned, 1.0)
+        w_eff = paper_w(planned) * a
 
     # per-round effective density: exact eig at small n, the power-iteration
     # estimate (the solvers' pre-screen) above ITERATIVE_MIN_N — at n=1024 a

@@ -27,8 +27,11 @@ import sys
 
 def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
         fleet: int = 2, model: int = 2, batch: int = 2, seq_len: int = 16,
-        eta: float = 0.05, tol: float = 1e-5) -> dict:
-    """Run the smoke; returns the report dict (key ``ok``)."""
+        eta: float = 0.05, tol: float = 1e-5,
+        n_nodes: int | None = None) -> dict:
+    """Run the smoke; returns the report dict (key ``ok``). ``n_nodes``
+    overrides the scenario's fleet size (the node axis shards only when it
+    divides ``fleet``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -47,7 +50,8 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
     adapter = transformer_adapter(arch, batch=batch, seq_len=seq_len)
     cfg = get_scenario(scenario, model_bits=adapter.model_bits,
                        model_shapes=adapter.param_shapes,
-                       eval_every_rounds=rounds)
+                       eval_every_rounds=rounds,
+                       **({} if n_nodes is None else {"n_nodes": n_nodes}))
     tb = precompute_traces([cfg], rounds)
     tr = tb.traces[0]
     batches = adapter.batch_fn(cfg, tr)
@@ -81,6 +85,9 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
         payload=cfg.payload, active_seq=jnp.asarray(tr.active))
     device_span = {d.id for leaf in jax.tree.leaves(final)
                    for d in leaf.sharding.device_set}
+    # leaves split across devices (replicated leaves span every device too)
+    sharded_leaves = sum(not leaf.sharding.is_fully_replicated
+                         for leaf in jax.tree.leaves(final))
     param_diff = max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                            - b.astype(jnp.float32))))
                      for a, b in zip(jax.tree.leaves(final),
@@ -108,6 +115,7 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
         "mesh": {"fleet": fleet, "model": model},
         "devices_visible": jax.device_count(),
         "devices_spanned": len(device_span),
+        "sharded_leaves": sharded_leaves,
         "model_bits": adapter.model_bits,
         "wire_bits": cfg.wire_bits(),
         "parity": {
@@ -122,7 +130,7 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
                         if out["acc"] is not None else None),
     }
     report["ok"] = bool(
-        len(device_span) >= 2
+        len(device_span) >= 2 and sharded_leaves > 0
         and param_diff <= tol and loss_diff <= tol
         and driver_loss_diff <= tol and driver_param_diff <= tol)
     return report

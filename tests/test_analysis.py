@@ -275,10 +275,9 @@ def test_dtype002_quiet_inside_x64_scope(tmp_path):
     r = run(tmp_path, {"src/repro/core/spec.py": """
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         def lam(ws):
-            with enable_x64():
+            with jax.enable_x64(True):
                 def _eig(m):
                     return jnp.abs(jnp.linalg.eigvals(m))
                 return jax.jit(jax.vmap(_eig))(ws)

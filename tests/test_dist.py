@@ -19,6 +19,7 @@ def _run(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"   # faked host devices; never the chip
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=900)
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
@@ -32,9 +33,8 @@ def test_shard_map_gossip_matches_dense_w():
         from jax.sharding import PartitionSpec as P
         from jax.experimental.shard_map import shard_map
         from repro.core.gossip import ring_plan, plan_w, gossip_mix_array
-        axt = getattr(jax.sharding, "AxisType", None)  # jax >= 0.5 only
-        kw = dict(axis_types=(axt.Auto,)) if axt else {}
-        mesh = jax.make_mesh((8,), ("data",), **kw)
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         plan = ring_plan(("data",), (8,), 2)
         x = jax.random.normal(jax.random.key(0), (8, 16))
         fn = shard_map(lambda v: gossip_mix_array(v[0], plan)[None],
@@ -59,9 +59,8 @@ def test_mode_b_trainstep_on_mesh_contains_collective_permute():
         from repro.optim.schedule import constant_lr
         from repro.train import shardings as shr
         from repro.train.step import init_train_state, make_train_step
-        axt = getattr(jax.sharding, "AxisType", None)  # jax >= 0.5 only
-        kw = dict(axis_types=(axt.Auto,) * 2) if axt else {}
-        mesh = jax.make_mesh((4, 2), ("data", "model"), **kw)
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = reduce_for_smoke(get_config("nemotron-4-15b"))
         api = build(cfg)
         run = RunConfig(mode="dpsgd", optimizer="sgd", remat="none")
@@ -125,9 +124,8 @@ def test_allreduce_mode_matches_single_node_sgd():
         tokens = jax.random.randint(jax.random.key(1), (8, 32), 0,
                                     cfg.vocab_size, jnp.int32)
         # sharded run
-        axt = getattr(jax.sharding, "AxisType", None)  # jax >= 0.5 only
-        kw = dict(axis_types=(axt.Auto,) * 2) if axt else {}
-        mesh = jax.make_mesh((4, 2), ("data", "model"), **kw)
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         b_sh = jax.device_put(tokens, NamedSharding(mesh, P("data", None)))
         with mesh:
             s1, m1 = jax.jit(step)(state, {"tokens": b_sh})

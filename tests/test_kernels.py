@@ -72,7 +72,8 @@ def test_default_interpret_tracks_live_backend(monkeypatch):
     assert _err(out, ref.gossip_mix_ref(bufs, w)) < 1e-5
     monkeypatch.setattr(jax, "default_backend",
                         lambda: (_ for _ in ()).throw(RuntimeError("boom")))
-    assert gm._default_interpret() is True          # failure-safe fallback
+    with pytest.raises(RuntimeError, match="boom"):  # no silent interpret
+        gm._default_interpret()
 
 
 @pytest.mark.parametrize("s,hq,hkv,d", [
@@ -148,6 +149,19 @@ def test_quantize_roundtrip(r, c):
     deq = ops.dequantize_int8(q, s)
     # error bounded by half an int8 step of the per-block scale
     assert _err(deq, x) <= float(jnp.abs(x).max()) / 127.0 * 0.51 + 1e-6
+
+
+@pytest.mark.parametrize("r,c", [(8, 6912), (16, 4352)])
+def test_quantize_covers_every_column_tile(r, c):
+    """Widths whose scale-block count is not a multiple of the column tile
+    (27 and 17 blocks): every column is quantized exactly as the oracle."""
+    x = jax.random.normal(jax.random.key(2), (r, c)) * 5
+    q, s = ops.quantize_int8(x)
+    qr, sr = ref.quantize_int8_ref(x)
+    assert jnp.all(q == qr)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(ops.dequantize_int8(q, s)),
+                                  np.asarray(ref.dequantize_int8_ref(q, s)))
 
 
 def test_quantize_matches_ref_exactly():

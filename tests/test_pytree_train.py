@@ -32,6 +32,7 @@ def _run(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"   # faked host devices; never the chip
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=900)
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
@@ -277,7 +278,7 @@ def test_quantconfig_and_scenario_validate_granularity():
 def test_node_param_specs_shards_node_axis_over_fleet():
     from jax.sharding import AbstractMesh, PartitionSpec as P
     from repro.train.shardings import node_param_specs
-    mesh = AbstractMesh((("fleet", 2), ("model", 2)))  # jax 0.4 pair form
+    mesh = AbstractMesh((2, 2), ("fleet", "model"))
     params = {"tok_emb": jnp.zeros((8, 16, 4)),      # divisible: shards
               "odd": jnp.zeros((7, 4))}              # 7 % 2: replicated
     specs = node_param_specs(params, mesh)
@@ -286,7 +287,7 @@ def test_node_param_specs_shards_node_axis_over_fleet():
     with pytest.raises(ValueError, match="scalar"):
         node_param_specs({"s": jnp.float32(0.0)}, mesh)
     # no-fleet mesh (model only): node axis always replicated
-    solo = AbstractMesh((("model", 2),))
+    solo = AbstractMesh((2,), ("model",))
     specs = node_param_specs(params, solo)
     assert specs["tok_emb"][0] is None
 
@@ -348,9 +349,12 @@ def test_transformer_leaf_compressed_trains_finite(tiny_transformer):
     assert np.isfinite(out["losses"]).all()
 
 
-def test_cnn_path_bit_identical_to_reference_loop():
-    """The CNN rides the generic pytree plane now; its losses must still be
-    bit-identical to the per-round reference of the same update sequence."""
+def test_cnn_path_matches_reference_loop_to_ulp():
+    """The CNN rides the generic pytree plane now; its losses must match
+    the per-round reference of the same update sequence to float32
+    round-off. Not bit for bit: the scan and the per-round step are
+    separate XLA programs, and fusion order moves the last ulp (1.2e-7 at
+    loss ~2.4 under jax 0.9, and differently again on the TPU)."""
     from repro.data import SyntheticFashion, node_splits
     from repro.models import cnn
     from repro.sim.batch import (_cnn_loss, _driver_batches,
@@ -376,8 +380,9 @@ def test_cnn_path_bit_identical_to_reference_loop():
     _, out = train_cnn_on_traces([cfg], epochs=1, batch=batch,
                                  n_train=n_train, n_test=60, trace_batch=tb)
     ref_mean = np.where(tr.live, ref_losses, 0.0).sum(-1) / tr.live.sum(-1)
-    np.testing.assert_array_equal(np.asarray(out["losses"][0]),
-                                  ref_mean.astype(out["losses"].dtype))
+    np.testing.assert_allclose(np.asarray(out["losses"][0]),
+                               ref_mean.astype(out["losses"].dtype),
+                               rtol=1e-6, atol=0)
 
 
 def test_screened_greedy_prefix_identical_to_unscreened():
