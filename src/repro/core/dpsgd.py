@@ -85,12 +85,14 @@ _MIX_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def mix(node_params: PyTree, w: jax.Array) -> PyTree:
-    """X <- W @ X on the leading node axis of every leaf."""
+    """X <- W @ X on the leading node axis of every leaf, under the
+    ``dpsgd.mix`` name scope."""
     def _mix(leaf: jax.Array) -> jax.Array:
         flat = leaf.reshape(leaf.shape[0], -1)
         return jnp.matmul(w.astype(flat.dtype), flat,
                           precision=_MIX_PRECISION).reshape(leaf.shape)
-    return jax.tree.map(_mix, node_params)
+    with jax.named_scope("dpsgd.mix"):
+        return jax.tree.map(_mix, node_params)
 
 
 def _node_grads(
@@ -99,8 +101,29 @@ def _node_grads(
     node_batches: PyTree,
 ) -> tuple[jax.Array, PyTree]:
     """Per-node loss/grads: vmap over the leading node axis of params+batch."""
-    losses, grads = jax.vmap(jax.value_and_grad(loss_fn))(node_params, node_batches)
-    return losses, grads
+    with jax.named_scope("dpsgd.grad"):
+        return jax.vmap(jax.value_and_grad(loss_fn))(node_params, node_batches)
+
+
+def _masked_node_grads(loss_fn, node_params: PyTree, node_batches: PyTree,
+                       live: jax.Array) -> tuple[jax.Array, PyTree]:
+    """``_node_grads`` with the rows of nodes not in ``live`` set to zero
+    (``where``, so NaNs from junk batch rows cannot leak)."""
+    losses, grads = _node_grads(loss_fn, node_params, node_batches)
+
+    def _mask(g: jax.Array) -> jax.Array:
+        m = live.reshape(live.shape[0], *([1] * (g.ndim - 1)))
+        return jnp.where(m, g, jnp.zeros((), dtype=g.dtype))
+
+    with jax.named_scope("dpsgd.grad"):
+        return losses, jax.tree.map(_mask, grads)
+
+
+def _sgd(node_params: PyTree, grads: PyTree, eta: float) -> PyTree:
+    """x - eta * g on every leaf, under the ``dpsgd.update`` name scope."""
+    with jax.named_scope("dpsgd.update"):
+        return jax.tree.map(lambda x, g: x - eta * g.astype(x.dtype),
+                            node_params, grads)
 
 
 @partial(jax.jit, static_argnames=("loss_fn", "config"))
@@ -125,23 +148,17 @@ def dpsgd_step(
     if h == 1:
         losses, grads = _node_grads(loss_fn, node_params, node_batches)
         if config.mix_first:
-            mixed = mix(node_params, w)
-            new_params = jax.tree.map(
-                lambda xm, g: xm - config.eta * g.astype(xm.dtype), mixed, grads)
+            new_params = _sgd(mix(node_params, w), grads, config.eta)
         else:
             # gradient-first order: X <- W (X - eta G). The previous
             # implementation skipped W entirely here, silently degenerating
             # to plain per-node SGD.
-            stepped = jax.tree.map(
-                lambda x, g: x - config.eta * g.astype(x.dtype),
-                node_params, grads)
-            new_params = mix(stepped, w)
+            new_params = mix(_sgd(node_params, grads, config.eta), w)
         return new_params, losses
 
     def local_step(params, batch):
         losses, grads = _node_grads(loss_fn, params, batch)
-        params = jax.tree.map(lambda x, g: x - config.eta * g.astype(x.dtype), params, grads)
-        return params, losses
+        return _sgd(params, grads, config.eta), losses
 
     def scan_body(params, batch):
         return local_step(params, batch)
@@ -192,22 +209,12 @@ def dpsgd_masked_step(
     if config.local_steps != 1:
         raise NotImplementedError(
             "dpsgd_masked_step supports local_steps == 1 only")
-    losses, grads = _node_grads(loss_fn, node_params, node_batches)
-
-    def _mask(g: jax.Array) -> jax.Array:
-        m = live.reshape(live.shape[0], *([1] * (g.ndim - 1)))
-        return jnp.where(m, g, jnp.zeros((), dtype=g.dtype))
-
-    grads = jax.tree.map(_mask, grads)
+    losses, grads = _masked_node_grads(loss_fn, node_params, node_batches,
+                                       live)
     if config.mix_first:
-        mixed = mix(node_params, w)
-        new_params = jax.tree.map(
-            lambda xm, g: xm - config.eta * g.astype(xm.dtype), mixed, grads)
+        new_params = _sgd(mix(node_params, w), grads, config.eta)
     else:
-        stepped = jax.tree.map(
-            lambda x, g: x - config.eta * g.astype(x.dtype),
-            node_params, grads)
-        new_params = mix(stepped, w)
+        new_params = mix(_sgd(node_params, grads, config.eta), w)
     return new_params, losses
 
 
@@ -262,9 +269,32 @@ def _mix_compressed(
         raise ValueError(
             f"live {live.shape} / w {w.shape} disagree with the node axis "
             f"n={n} of node_params")
-    if getattr(quant, "granularity", "message") == "leaf":
-        return _mix_compressed_leaf(node_params, residuals, w, live, quant)
-    return _mix_compressed_message(node_params, residuals, w, live, quant)
+    by_leaf = getattr(quant, "granularity", "message") == "leaf"
+    with jax.named_scope("dpsgd.mix"):
+        return (_mix_compressed_leaf if by_leaf else _mix_compressed_message)(
+            node_params, residuals, w, live, quant)
+
+
+def _quantize(flat: jax.Array, res: jax.Array, live_col: jax.Array,
+              quant) -> tuple[jax.Array, jax.Array]:
+    """One wire buffer's sender side, under the ``dpsgd.quantize`` name
+    scope: the (n, size) rows ``flat`` plus their error-feedback residuals
+    ``res``, quantized and dequantized as the receivers see them, and the
+    new residuals, zero in rows not in ``live_col``."""
+    from .compression import dequantize_int8_rows, quantize_int8_rows
+
+    with jax.named_scope("dpsgd.quantize"):
+        carried = flat + (res if quant.error_feedback else 0.0)
+        if quant.mode == "bf16":
+            deq = carried.astype(jnp.bfloat16).astype(jnp.float32)
+        elif quant.mode == "int8":
+            q, scale = quantize_int8_rows(carried)
+            deq = dequantize_int8_rows(q, scale, carried.shape[1])
+        else:
+            raise ValueError(f"unknown compression mode {quant.mode!r}")
+        new_res = carried - deq if quant.error_feedback else res
+        return deq, jnp.where(live_col, new_res,
+                              jnp.zeros((), new_res.dtype))
 
 
 def _mix_compressed_message(
@@ -275,25 +305,13 @@ def _mix_compressed_message(
     quant,
 ) -> tuple[PyTree, PyTree]:
     """Concat-flat wire format: one quantized buffer per node per round."""
-    from .compression import dequantize_int8_rows, quantize_int8_rows
-
     leaves, treedef = jax.tree.flatten(node_params)
     res_leaves = treedef.flatten_up_to(residuals)
     n = leaves[0].shape[0]
     flat = jnp.concatenate(
         [p.reshape(n, -1).astype(jnp.float32) for p in leaves], axis=1)
     res = jnp.concatenate([r.reshape(n, -1) for r in res_leaves], axis=1)
-    carried = flat + (res if quant.error_feedback else 0.0)
-    if quant.mode == "bf16":
-        deq = carried.astype(jnp.bfloat16).astype(jnp.float32)
-    elif quant.mode == "int8":
-        q, scale = quantize_int8_rows(carried)
-        deq = dequantize_int8_rows(q, scale, carried.shape[1])
-    else:
-        raise ValueError(f"unknown compression mode {quant.mode!r}")
-    new_res = carried - deq if quant.error_feedback else res
-    new_res = jnp.where(live.reshape(n, 1), new_res,
-                        jnp.zeros((), new_res.dtype))
+    deq, new_res = _quantize(flat, res, live.reshape(n, 1), quant)
     w32 = w.astype(jnp.float32)
     diag = jnp.diagonal(w32)
     off = w32 - jnp.diag(diag)
@@ -322,8 +340,6 @@ def _mix_compressed_leaf(
     and carries its own error-feedback residual, so sharded leaves never
     gather. ``payload_bits_tree(..., granularity="leaf")`` charges the
     per-leaf tail padding this implies."""
-    from .compression import dequantize_int8_rows, quantize_int8_rows
-
     w32 = w.astype(jnp.float32)
     diag = jnp.diagonal(w32)
     off = w32 - jnp.diag(diag)
@@ -332,17 +348,7 @@ def _mix_compressed_leaf(
     def _one(p: jax.Array, r: jax.Array) -> tuple[jax.Array, jax.Array]:
         n = p.shape[0]
         flat = p.reshape(n, -1).astype(jnp.float32)
-        res = r.reshape(n, -1)
-        carried = flat + (res if quant.error_feedback else 0.0)
-        if quant.mode == "bf16":
-            deq = carried.astype(jnp.bfloat16).astype(jnp.float32)
-        elif quant.mode == "int8":
-            q, scale = quantize_int8_rows(carried)
-            deq = dequantize_int8_rows(q, scale, carried.shape[1])
-        else:
-            raise ValueError(f"unknown compression mode {quant.mode!r}")
-        new_res = carried - deq if quant.error_feedback else res
-        new_res = jnp.where(live_col, new_res, jnp.zeros((), new_res.dtype))
+        deq, new_res = _quantize(flat, r.reshape(n, -1), live_col, quant)
         mixed = diag[:, None] * flat + jnp.matmul(off, deq,
                                               precision=_MIX_PRECISION)
         return mixed.reshape(p.shape).astype(p.dtype), new_res.reshape(p.shape)
@@ -386,24 +392,15 @@ def dpsgd_masked_compressed_step(
     if config.local_steps != 1:
         raise NotImplementedError(
             "dpsgd_masked_compressed_step supports local_steps == 1 only")
-    losses, grads = _node_grads(loss_fn, node_params, node_batches)
-
-    def _mask(g: jax.Array) -> jax.Array:
-        m = live.reshape(live.shape[0], *([1] * (g.ndim - 1)))
-        return jnp.where(m, g, jnp.zeros((), dtype=g.dtype))
-
-    grads = jax.tree.map(_mask, grads)
+    losses, grads = _masked_node_grads(loss_fn, node_params, node_batches,
+                                       live)
     if config.mix_first:
         mixed, new_res = _mix_compressed(node_params, residuals, w, live,
                                          quant)
-        new_params = jax.tree.map(
-            lambda xm, g: xm - config.eta * g.astype(xm.dtype), mixed, grads)
+        new_params = _sgd(mixed, grads, config.eta)
     else:
-        stepped = jax.tree.map(
-            lambda x, g: x - config.eta * g.astype(x.dtype),
-            node_params, grads)
-        new_params, new_res = _mix_compressed(stepped, residuals, w, live,
-                                              quant)
+        new_params, new_res = _mix_compressed(
+            _sgd(node_params, grads, config.eta), residuals, w, live, quant)
     return new_params, new_res, losses
 
 

@@ -36,6 +36,7 @@ from typing import Callable, Literal, Optional
 
 import numpy as np
 
+from ..utils.spans import span
 from .comm_model import tdm_time_batch_s, tdm_time_s
 from .topology import (ITERATIVE_MIN_N, adjacency_from_rates,
                        adjacency_from_rates_batch, paper_w, spectral_lambda,
@@ -296,7 +297,14 @@ def certified_best(
     """
     rates = np.atleast_2d(np.asarray(rates, dtype=np.float64))
     t = tdm_time_batch_s(model_bits, rates)
-    lam_est = _lambda_iter_chunked(capacity, rates, reception_based, iters)
+    with span("plan.screen", candidates=rates.shape[0], n=rates.shape[1]):
+        lam_est = _lambda_iter_chunked(capacity, rates, reception_based, iters)
+
+    def certify(idx) -> RateSolution:
+        with span("plan.certify"):
+            return _evaluate(capacity, rates[idx], model_bits, lambda_target,
+                             reception_based)
+
     order = np.argsort(t, kind="stable")
     screened = order[lam_est[order] <= lambda_target + 1e-9]
     certs = 0
@@ -304,8 +312,7 @@ def certified_best(
         if certs >= cert_budget:
             break
         certs += 1
-        sol = _evaluate(capacity, rates[idx], model_bits, lambda_target,
-                        reception_based)
+        sol = certify(idx)
         if sol.feasible:
             return sol
     # estimate misjudged the screened set: try the smallest-estimate picks
@@ -313,13 +320,11 @@ def certified_best(
         if certs >= 2 * cert_budget:
             break
         certs += 1
-        sol = _evaluate(capacity, rates[idx], model_bits, lambda_target,
-                        reception_based)
+        sol = certify(idx)
         if sol.feasible:
             return sol
     # nothing certifies: report the densest attempt (smallest estimate)
-    return _evaluate(capacity, rates[int(np.argmin(lam_est))], model_bits,
-                     lambda_target, reception_based)
+    return certify(int(np.argmin(lam_est)))
 
 
 def _combo_rates(per_node: list[np.ndarray], flat_idx: np.ndarray) -> np.ndarray:
@@ -779,21 +784,31 @@ def solve(
         ref = method == "auto_reference"
         if n <= 7:
             bf = solve_bruteforce_reference if ref else solve_bruteforce
-            return bf(capacity, model_bits, lambda_target,
-                      reception_based=reception_based)
+            return _run_solver(bf, capacity, model_bits, lambda_target,
+                               reception_based)
         if n > ITERATIVE_MIN_N and not ref:
             trio = (solve_k_nearest, solve_common_rate)
         else:
             trio = (solve_greedy_reference, solve_k_nearest_reference,
                     solve_common_rate_reference) if ref else \
                    (solve_greedy, solve_k_nearest, solve_common_rate)
-        sols = [f(capacity, model_bits, lambda_target, reception_based=reception_based)
-                for f in trio]
+        sols = [_run_solver(f, capacity, model_bits, lambda_target,
+                            reception_based) for f in trio]
         feasible = [s for s in sols if s.feasible]
         pool = feasible if feasible else sols
         return min(pool, key=lambda s: s.t_com_s)
-    return _SOLVERS[method](capacity, model_bits, lambda_target,
-                            reception_based=reception_based)
+    return _run_solver(_SOLVERS[method], capacity, model_bits, lambda_target,
+                       reception_based)
+
+
+def _run_solver(solver: Callable[..., RateSolution], capacity: np.ndarray,
+                model_bits: float, lambda_target: float,
+                reception_based: bool) -> RateSolution:
+    """One solver of ``solve``, in a ``repro.plan.solve`` span named by its
+    method."""
+    with span("plan.solve", method=solver.__name__.removeprefix("solve_")):
+        return solver(capacity, model_bits, lambda_target,
+                      reception_based=reception_based)
 
 
 def _payload_modes() -> tuple[str, ...]:

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.compression import QuantConfig
+from ..utils.spans import span
 from ..core.dpsgd import (DPSGDConfig, dpsgd_masked_compressed_step,
                           dpsgd_masked_step, node_axis_size, replicate,
                           zero_residuals)
@@ -528,12 +529,27 @@ def train_model_on_traces(
     ``losses`` (S, rounds), eval-round metrics ``acc`` (S, E) with
     simulated-time stamps ``t_acc_s`` (None when the adapter has no
     ``eval_fn``), ``curves``, per-trace compacted ``final_params``, and
-    watchdog ``rollbacks``."""
-    from ..checkpoint.ckpt import compact_nodes
+    watchdog ``rollbacks``.
 
+    The call is the host span ``repro.train`` (``traces``, ``rounds``,
+    ``nodes``), in three parts: ``repro.train.prep`` (batches, initial
+    parameters, their replication and the uploads), ``repro.train.run``
+    (the compiled call and the losses' readback) and ``repro.train.post``
+    (masked means, eval, compaction)."""
     cfgs = [get_scenario(c) if isinstance(c, str) else c for c in configs]
     if not cfgs:
         raise ValueError("train_model_on_traces needs at least one config")
+    with span("train", traces=len(cfgs), rounds=int(n_rounds),
+              nodes=cfgs[0].n_nodes):
+        return _train_model_on_traces(adapter, cfgs, n_rounds, eta,
+                                      trace_batch, unroll, engine, mesh)
+
+
+def _train_model_on_traces(adapter, cfgs, n_rounds, eta, trace_batch, unroll,
+                           engine, mesh):
+    """The body of ``train_model_on_traces``."""
+    from ..checkpoint.ckpt import compact_nodes
+
     n_nodes = cfgs[0].n_nodes
     eval_every = cfgs[0].eval_every_rounds
     payload = cfgs[0].payload
@@ -575,15 +591,19 @@ def train_model_on_traces(
             raise ValueError(
                 f"trace realized under {t.cfg} cannot train config {c}")
 
-    built = [adapter.batch_fn(c, t) for c, t in zip(cfgs, traces.traces)]
-    batches = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *built)
-    inits = jax.tree.map(lambda *xs: jnp.stack(xs),
-                         *[adapter.init_params(c.seed) for c in cfgs])
-    if mesh is not None:
-        params0, batches = _shard_family(inits, n_nodes, batches, mesh)
-    else:
-        params0 = _replicate_family(inits, n_nodes)
-    del inits
+    with span("train.prep"):
+        built = [adapter.batch_fn(c, t) for c, t in zip(cfgs, traces.traces)]
+        batches = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *built)
+        inits = jax.tree.map(lambda *xs: jnp.stack(xs),
+                             *[adapter.init_params(c.seed) for c in cfgs])
+        if mesh is not None:
+            params0, batches = _shard_family(inits, n_nodes, batches, mesh)
+        else:
+            params0 = _replicate_family(inits, n_nodes)
+        del inits
+        w_seq = jnp.asarray(traces.w_eff)
+        live_seq = jnp.asarray(traces.live)
+        active_seq = jnp.asarray(traces.active)
 
     eval_rounds = [r for r in range(n_rounds)
                    if (r + 1) % eval_every == 0 or r + 1 == n_rounds]
@@ -591,55 +611,57 @@ def train_model_on_traces(
     # final parameters, so the scan carries only the earlier ones
     snapshot_rounds = (tuple(eval_rounds[:-1]) if adapter.eval_fn is not None
                        else ())
-    out_arrays = train_on_traces(
-        adapter.loss_fn, params0,
-        jnp.asarray(traces.w_eff), jnp.asarray(traces.live), batches,
-        DPSGDConfig(eta=eta), snapshot_rounds=snapshot_rounds,
-        params_batched=True, unroll=unroll, payload=payload,
-        active_seq=jnp.asarray(traces.active), watchdog=watchdog)
-    finals, losses = out_arrays[:2]
-    snaps = out_arrays[2] if snapshot_rounds else None
-    rollbacks = out_arrays[-1] if watchdog else None
+    with span("train.run"):
+        out_arrays = train_on_traces(
+            adapter.loss_fn, params0, w_seq, live_seq, batches,
+            DPSGDConfig(eta=eta), snapshot_rounds=snapshot_rounds,
+            params_batched=True, unroll=unroll, payload=payload,
+            active_seq=active_seq, watchdog=watchdog)
+        raw = np.asarray(out_arrays[1], dtype=np.float64)  # (S, rounds, n)
+    with span("train.post"):
+        finals = out_arrays[0]
+        snaps = out_arrays[2] if snapshot_rounds else None
+        rollbacks = out_arrays[-1] if watchdog else None
 
-    live = traces.live                                    # (S, rounds, n)
-    raw = np.asarray(losses, dtype=np.float64)            # (S, rounds, n)
-    # where, not multiply: dead-row filler may legally produce NaN losses
-    masked = np.where(live, raw, 0.0)
-    mean_losses = masked.sum(-1) / live.sum(-1)           # masked driver mean
+        live = traces.live                                # (S, rounds, n)
+        # where, not multiply: dead-row filler may legally produce NaN losses
+        masked = np.where(live, raw, 0.0)
+        mean_losses = masked.sum(-1) / live.sum(-1)  # masked per-round mean
 
-    s_count = traces.n_traces
-    if adapter.eval_fn is not None:
-        first = np.argmax(live[:, -1], axis=1)           # (S,) row at the end
-        last = jax.tree.map(lambda p: p[np.arange(s_count), first][:, None],
-                            finals)
-        if snaps is not None:
-            last = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=1),
-                                snaps, last)
-        sel = jax.tree.map(
-            lambda p: p.reshape((s_count * len(eval_rounds),) + p.shape[2:]),
-            last)
-        accs = jax.vmap(adapter.eval_fn)(sel)
-        accs = np.asarray(accs, dtype=np.float64).reshape(
-            s_count, len(eval_rounds))
-        t_acc = traces.t_end_s[:, eval_rounds]
-        curves = [list(zip(t_acc[s].tolist(), accs[s].tolist()))
-                  for s in range(s_count)]
-    else:
-        accs, t_acc, curves = None, None, None
-    final_params = [
-        compact_nodes(jax.tree.map(lambda p, s=s: p[s], finals), live[s, -1])
-        for s in range(s_count)]
-    return traces, {
-        "losses": mean_losses,
-        "acc": accs,
-        "t_acc_s": t_acc,
-        "eval_rounds": eval_rounds,
-        "curves": curves,
-        "final_params": final_params,
-        # (S, rounds, n) bool watchdog rollback events, None when disarmed
-        "rollbacks": (np.asarray(rollbacks) if rollbacks is not None
-                      else None),
-    }
+        s_count = traces.n_traces
+        if adapter.eval_fn is not None:
+            first = np.argmax(live[:, -1], axis=1)   # (S,) row at the end
+            last = jax.tree.map(
+                lambda p: p[np.arange(s_count), first][:, None], finals)
+            if snaps is not None:
+                last = jax.tree.map(
+                    lambda a, b: jnp.concatenate([a, b], axis=1), snaps, last)
+            sel = jax.tree.map(
+                lambda p: p.reshape((s_count * len(eval_rounds),)
+                                    + p.shape[2:]), last)
+            accs = jax.vmap(adapter.eval_fn)(sel)
+            accs = np.asarray(accs, dtype=np.float64).reshape(
+                s_count, len(eval_rounds))
+            t_acc = traces.t_end_s[:, eval_rounds]
+            curves = [list(zip(t_acc[s].tolist(), accs[s].tolist()))
+                      for s in range(s_count)]
+        else:
+            accs, t_acc, curves = None, None, None
+        final_params = [
+            compact_nodes(jax.tree.map(lambda p, s=s: p[s], finals),
+                          live[s, -1])
+            for s in range(s_count)]
+        return traces, {
+            "losses": mean_losses,
+            "acc": accs,
+            "t_acc_s": t_acc,
+            "eval_rounds": eval_rounds,
+            "curves": curves,
+            "final_params": final_params,
+            # (S, rounds, n) bool watchdog rollback events, None when disarmed
+            "rollbacks": (np.asarray(rollbacks) if rollbacks is not None
+                          else None),
+        }
 
 
 def train_cnn_on_traces(

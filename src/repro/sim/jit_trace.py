@@ -52,6 +52,7 @@ import numpy as np
 from ..core import channel
 from ..core.topology import ITERATIVE_MIN_N, paper_w, spectral_lambda, \
     spectral_lambda_iter_batch
+from ..utils.spans import span
 from .mac import _packets, mean_drift
 from .scenario import ScenarioConfig, get_scenario
 
@@ -133,12 +134,13 @@ def _round_scan(n: int, n_pkts: int, passes: int, fading_on: bool,
     The returned function maps ``(rates, sizes, recv, chan)`` to per-round
     ``(t_start, t_comm, delivered, retx)`` stacks plus the final clock.
     ``chan`` is the raw mean SNR matrix under fading, else the precomputed
-    static decode table ``capacity >= rate_i``.
+    static decode table ``capacity >= rate_i``. Compile events and the
+    profiler name the program ``channel_round_scan``.
     """
     import jax
     import jax.numpy as jnp
 
-    def run(rates, sizes, recv, chan):
+    def channel_round_scan(rates, sizes, recv, chan):
         active = jnp.isfinite(rates) & (rates > 0)
         durs = (sizes[None, :] / jnp.where(active, rates, 1.0)[:, None]
                 + overhead_s)                                  # (n, P)
@@ -180,7 +182,7 @@ def _round_scan(n: int, n_pkts: int, passes: int, fading_on: bool,
                                    length=n_rounds)
         return outs + (clock,)
 
-    return jax.jit(run)
+    return jax.jit(channel_round_scan)
 
 
 def precompute_trace_scan(cfg, n_rounds: int, sim=None, **overrides):
@@ -196,10 +198,16 @@ def precompute_trace_scan(cfg, n_rounds: int, sim=None, **overrides):
     ``sim`` lets a caller that already paid the replan (``WirelessSimulator
     (cfg)``) hand it over instead of planning twice; it must have been built
     from this exact ``cfg`` (no ``overrides`` then).
+
+    The realization is the host span ``repro.scan`` (placement ``seed``,
+    ``n``, ``rounds``, ``packets``), in three parts: ``repro.scan.prepare``
+    (the program's inputs), ``repro.scan.run`` (the compiled call and its
+    readback) and ``repro.scan.records`` (Eq. 4 W, effective densities,
+    round records).
     """
     import jax
 
-    from .trace import RoundRecord, SimTrace, TrainTrace, WirelessSimulator
+    from .trace import WirelessSimulator
 
     if isinstance(cfg, str):
         cfg = get_scenario(cfg, **overrides)
@@ -212,17 +220,29 @@ def precompute_trace_scan(cfg, n_rounds: int, sim=None, **overrides):
     elif overrides or sim.cfg is not cfg:
         raise ValueError("pass sim= only with the exact cfg it was built "
                          "from (and no overrides)")
-    sol = sim.solution
-    n = cfg.n_nodes
-    rates = np.asarray(sol.rates_bps, dtype=np.float64)
-    if np.isnan(rates).any():
-        raise ValueError("plan has NaN rates")
-    recv = np.asarray(sim._intended, dtype=bool).copy()
-    np.fill_diagonal(recv, False)
     sizes = np.asarray(_packets(cfg.model_bits, cfg.mac.packet_bits),
                        dtype=np.float64)
     if sizes.size == 0:
         raise ValueError("zero-bit model: nothing to put on the air")
+    with span("scan", seed=int(cfg.seed), n=cfg.n_nodes,
+              rounds=int(n_rounds), packets=int(sizes.size)):
+        with span("scan.prepare"):
+            fn, rates, recv, chan = _scan_inputs(cfg, sim, sizes, n_rounds)
+        with span("scan.run"), jax.enable_x64(True):
+            out = [np.asarray(x) for x in fn(rates, sizes, recv, chan)]
+        with span("scan.records"):
+            return _host_train_trace(cfg, sim.solution, rates, recv,
+                                     sizes, n_rounds, *out)
+
+
+def _scan_inputs(cfg, sim, sizes: np.ndarray, n_rounds: int):
+    """The compiled round loop for ``cfg``'s shape, and its inputs: the
+    plan's rates, its intended links and the channel table."""
+    rates = np.asarray(sim.solution.rates_bps, dtype=np.float64)
+    if np.isnan(rates).any():
+        raise ValueError("plan has NaN rates")
+    recv = np.asarray(sim._intended, dtype=bool).copy()
+    np.fill_diagonal(recv, False)
     pos = sim._positions()
 
     fading_on = cfg.fading is not None
@@ -237,15 +257,21 @@ def precompute_trace_scan(cfg, n_rounds: int, sim=None, **overrides):
         chan = cap >= rates[:, None]
         coherence_s = 1.0
         seed = 0
-    fn = _round_scan(n, int(sizes.size), 1 + int(cfg.mac.max_retx_rounds),
+    fn = _round_scan(cfg.n_nodes, int(sizes.size),
+                     1 + int(cfg.mac.max_retx_rounds),
                      fading_on, coherence_s, float(cfg.bandwidth_hz),
                      float(cfg.mac.per_packet_overhead_s),
                      float(cfg.compute_s_per_round), seed, int(n_rounds))
-    with jax.enable_x64(True):
-        out = fn(rates, sizes, recv, chan)
-        t_start, t_comm, delivered, retx, t_end = \
-            [np.asarray(x) for x in out]
+    return fn, rates, recv, chan
 
+
+def _host_train_trace(cfg, sol, rates, recv, sizes, n_rounds, t_start,
+                      t_comm, delivered, retx, t_end):
+    """The ``TrainTrace`` of the scan's outputs: W of each round by Eq. 4
+    from its delivered graph, effective densities and round records."""
+    from .trace import RoundRecord, SimTrace, TrainTrace
+
+    n = cfg.n_nodes
     # W from the delivered graph with the event loop's Eq. 4 code
     # (``RoundResult.effective_w``), batched over rounds
     a = delivered.transpose(0, 2, 1).astype(np.float64)  # a[j, i]: j got i

@@ -39,6 +39,7 @@ from collections import deque
 
 from ..core.topology import adjacency_from_rates, spectral_lambda
 from ..runtime.fault import ElasticController
+from ..utils.spans import span
 from .events import EventKind, EventQueue, SimClock
 from .fading import FadingChannel
 from .faults import FaultSchedule
@@ -296,10 +297,20 @@ class WirelessSimulator:
         planner that raises on a degenerate survivor graph degrades to the
         policy's common-rate ``fallback`` plan instead of crashing the run,
         and the solver is retried with doubling backoff
-        (``_replan_cooldown``) rather than every round."""
-        m = self._mean_capacity()
-        self.controller.capacity = m
-        m_plan = self._plan_capacity(m)
+        (``_replan_cooldown``) rather than every round.
+
+        The whole replan is the host span ``repro.plan`` (placement
+        ``seed``, live ``n``), with ``repro.plan.capacity`` and
+        ``repro.plan.links`` inside it; ``rate_opt`` adds the solver's."""
+        with span("plan", seed=int(self.cfg.seed), n=len(self.ids)):
+            self._plan()
+
+    def _plan(self):
+        """The body of ``_replan``."""
+        with span("plan.capacity"):
+            m = self._mean_capacity()
+            self.controller.capacity = m
+            m_plan = self._plan_capacity(m)
         n = len(self.ids)
         surv = np.flatnonzero(~self._suspect[:n])
         sub = m_plan[np.ix_(surv, surv)] if surv.size < n else m_plan
@@ -315,11 +326,13 @@ class WirelessSimulator:
             self.payload_mode = sol.mode
             self.wire_bits = float(sol.wire_bits)
         rates = np.asarray(sol.rates_bps, dtype=np.float64)
-        intended_sub = adjacency_from_rates(sub, rates).astype(bool)
-        if (~(np.isfinite(rates) & (rates > 0))).any():
-            # a zero/inf rate means "silent", but C >= 0 holds for every
-            # receiver — mask those rows off instead of intending the world
-            intended_sub[~(np.isfinite(rates) & (rates > 0))] = False
+        with span("plan.links"):
+            intended_sub = adjacency_from_rates(sub, rates).astype(bool)
+            if (~(np.isfinite(rates) & (rates > 0))).any():
+                # a zero/inf rate means "silent", but C >= 0 holds for every
+                # receiver — mask those rows off instead of intending the
+                # world
+                intended_sub[~(np.isfinite(rates) & (rates > 0))] = False
         if surv.size < n:
             self.solution = _expand_solution(sol, surv, n)
             intended = np.zeros((n, n), dtype=bool)
