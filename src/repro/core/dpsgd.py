@@ -6,7 +6,15 @@ State layout: every parameter leaf carries a leading **node axis** of size n
     X_{k+1} <- W @ X_k  -  eta * stack_i( grad F_i(x_{k,i}; xi_{k,i}) )
 
 One step = (a) per-node minibatch gradients via ``jax.vmap`` over the node
-axis, (b) mixing via einsum with the averaging matrix W, (c) SGD update.
+axis, (b) mixing with the averaging matrix W, (c) SGD update. Mixing takes
+one of two paths. Where every node sits on one device (the unsharded steps,
+the CNN sweeps, a node axis that does not divide over a mesh's fleet axes,
+the compressed mixes), ``mix`` is a dense fp32 ``W @ X`` matmul. Where a
+mesh's fleet axes shard the node axis, ``exchange_mix`` computes the same
+W X inside ``shard_map``: each chip gathers every chip's block of node
+rows and combines them elementwise with its own rows of W, fused with the
+update, in place of a matmul over the gathered node axis and a separate
+update pass.
 This runs the *mathematics* of n wireless nodes exactly on one host; the
 wall-clock communication cost is modeled separately by ``comm_model.tdm_time_s``
 (exactly how the paper itself evaluates runtime: measured compute + Eq. 3).
@@ -24,9 +32,12 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-__all__ = ["DPSGDConfig", "replicate", "mix", "dpsgd_step", "make_dpsgd_step",
-           "dpsgd_masked_step", "make_dpsgd_masked_step",
+from ..launch.mesh import fleet_size, replica_axes
+
+__all__ = ["DPSGDConfig", "replicate", "mix", "exchange_mix", "dpsgd_step",
+           "make_dpsgd_step", "dpsgd_masked_step", "make_dpsgd_masked_step",
            "dpsgd_masked_compressed_step",
            "make_dpsgd_compressed_step", "embed_w", "zero_residuals",
            "node_axis_size"]
@@ -93,6 +104,72 @@ def mix(node_params: PyTree, w: jax.Array) -> PyTree:
                           precision=_MIX_PRECISION).reshape(leaf.shape)
     with jax.named_scope("dpsgd.mix"):
         return jax.tree.map(_mix, node_params)
+
+
+def exchange_mix(node_params: PyTree, w: jax.Array, mesh,
+                 grads: PyTree = None, eta: float = 0.0) -> PyTree:
+    """X <- W @ X (then ``- eta * grads`` when given) with the node axis
+    sharded over ``mesh``'s fleet axes (every axis but ``'model'``), inside
+    ``jax.shard_map`` over the layout of ``train.shardings.node_param_specs``,
+    so a leaf sharded over ``'model'`` is gathered over the fleet axes only
+    and stays sharded.
+
+    Chip c holds the b = n / F node rows c*b .. c*b + b - 1. One
+    ``all_gather`` per leaf over the fleet axes brings it every chip's
+    block, and its output rows are the sum over every node i, in node index
+    order, of ``W[rows_c, i] * x_i``: elementwise fp32 multiply-adds with
+    the weights sliced from the traced W, so W stays runtime data, an
+    identity row returns its node's parameters bit for bit, and the
+    combine and the update are one elementwise pass that XLA fuses, in
+    place of a matmul over the node axis and a separate update. The
+    exchange and the combine run under the ``dpsgd.mix`` name scope, the
+    update under ``dpsgd.update``. ``mix`` computes the same result where
+    all nodes sit on one device.
+
+    Why a gather and not ``ppermute`` shifts: every node hears every other
+    in a dense W, so each chip receives F - 1 blocks either way, and on a
+    TPU v5e 2x2 one ``ppermute`` moves a block over a single link while the
+    all-gather drives every link at once (PERF.md, section 6)."""
+    # imported here: the train package imports core on its own import
+    from ..train.shardings import node_param_specs
+
+    axes = replica_axes(mesh)
+    fleet = fleet_size(mesh)
+    n = node_axis_size(node_params, "node_params")
+    if n % fleet:
+        raise ValueError(
+            f"{n} nodes do not divide over the {fleet} fleet slots of "
+            f"mesh axes {axes}")
+    b = n // fleet
+    axis = axes if len(axes) > 1 else axes[0]
+
+    def body(x_tree, w, g_tree=None):
+        c = jax.lax.axis_index(axis)
+        rows = jax.lax.dynamic_slice_in_dim(
+            w.astype(jnp.float32), c * b, b, axis=0)          # (b, n)
+
+        def _mix(x: jax.Array) -> jax.Array:
+            # (F, b, ...): every chip's block, in node-index order
+            xs = jax.lax.all_gather(x, axis, axis=0, tiled=False)
+            col = (b,) + (1,) * (x.ndim - 1)
+            acc = None
+            for j in range(fleet):
+                for m in range(b):
+                    term = (rows[:, j * b + m].reshape(col)
+                            * xs[j, m].astype(jnp.float32)[None])
+                    acc = term if acc is None else acc + term
+            return acc.astype(x.dtype)
+
+        with jax.named_scope("dpsgd.mix"):
+            mixed = jax.tree.map(_mix, x_tree)
+        return mixed if g_tree is None else _sgd(mixed, g_tree, eta)
+
+    spec = node_param_specs(node_params, mesh)
+    args, specs = (node_params, w), (spec, P())
+    if grads is not None:
+        args, specs = args + (grads,), specs + (spec,)
+    return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                         out_specs=spec)(*args)
 
 
 def _node_grads(
@@ -192,6 +269,7 @@ def dpsgd_masked_step(
     w: jax.Array,
     live: jax.Array,
     config: DPSGDConfig = DPSGDConfig(),
+    mesh=None,
 ) -> tuple[PyTree, jax.Array]:
     """One D-PSGD iteration on a fixed-width node state under churn.
 
@@ -205,12 +283,22 @@ def dpsgd_masked_step(
 
     Only ``local_steps == 1`` is supported (the scan path mixes every round,
     like the paper's Algorithm 1).
+
+    ``mesh``, when given, is a mesh whose fleet axes shard the node axis
+    (``train.shardings.node_param_specs``): W X is then ``exchange_mix``,
+    fused with the update, in place of the dense ``mix``.
     """
     if config.local_steps != 1:
         raise NotImplementedError(
             "dpsgd_masked_step supports local_steps == 1 only")
     losses, grads = _masked_node_grads(loss_fn, node_params, node_batches,
                                        live)
+    if mesh is not None:
+        if config.mix_first:
+            return exchange_mix(node_params, w, mesh, grads,
+                                config.eta), losses
+        return exchange_mix(_sgd(node_params, grads, config.eta), w,
+                            mesh), losses
     if config.mix_first:
         new_params = _sgd(mix(node_params, w), grads, config.eta)
     else:

@@ -8,7 +8,7 @@ from __future__ import annotations
 import jax
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_fleet_mesh",
-           "replica_axes", "tp_size"]
+           "replica_axes", "fleet_size", "tp_size"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -52,6 +52,14 @@ def make_fleet_mesh(fleet: int = 2, model: int = 2):
 def replica_axes(mesh) -> tuple[str, ...]:
     """The D-PSGD node axes = every axis except 'model'."""
     return tuple(a for a in mesh.axis_names if a != "model")
+
+
+def fleet_size(mesh) -> int:
+    """Number of node slots: the product of the ``replica_axes`` sizes."""
+    size = 1
+    for a in replica_axes(mesh):
+        size *= int(mesh.shape[a])
+    return size
 
 
 def tp_size(mesh) -> int:

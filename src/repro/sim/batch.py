@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.compression import QuantConfig
+from ..launch.mesh import fleet_size, replica_axes
 from ..utils.spans import span
 from ..core.dpsgd import (DPSGDConfig, dpsgd_masked_compressed_step,
                           dpsgd_masked_step, node_axis_size, replicate,
@@ -84,7 +85,7 @@ def _row_where(mask: jax.Array, a: PyTree, b: PyTree) -> PyTree:
 
 @partial(jax.jit,
          static_argnames=("loss_fn", "config", "snapshot_rounds", "unroll",
-                          "payload", "watchdog"))
+                          "payload", "watchdog", "mesh"))
 def train_on_trace(
     loss_fn: Callable[[PyTree, PyTree], Any],
     node_params: PyTree,
@@ -97,6 +98,7 @@ def train_on_trace(
     payload: QuantConfig = _NO_PAYLOAD,
     active_seq=None,
     watchdog: bool = False,
+    mesh=None,
 ):
     """Train over one precomputed trace in a single ``lax.scan``.
 
@@ -137,6 +139,11 @@ def train_on_trace(
     its last finite snapshot (error-feedback residuals reset to zero on
     rollback so poisoned quantization error cannot re-infect it). Returns
     one extra (rounds, n) bool array of rollback events as the last output.
+
+    ``mesh``, when given, is the mesh whose fleet axes shard the node axis
+    of ``node_params`` (see ``_exchange_mesh``): each round then mixes by
+    ``core.dpsgd.exchange_mix`` in place of the dense W matmul. The payload
+    must then be exact.
     """
     if payload.mode == "auto":
         raise ValueError(
@@ -144,6 +151,12 @@ def train_on_trace(
             "resolved by the joint planner at simulation time — train with "
             "the mode the plan actually picked")
     compressed = payload.mode != "none"
+    if compressed and mesh is not None:
+        raise ValueError(
+            "the exchange mix carries the exact payload only; the "
+            f"compressed mode {payload.mode!r} mixes on the dense path")
+    masked_step = (dpsgd_masked_step if mesh is None
+                   else partial(dpsgd_masked_step, mesh=mesh))
     n_snap = len(snapshot_rounds)
     # snapshot slot per round: its index in snapshot_rounds, else the spare
     # slot n_snap
@@ -163,7 +176,7 @@ def train_on_trace(
             new_params, new_res, losses = dpsgd_masked_compressed_step(
                 loss_fn, params, batch, w, active, res, payload, config)
         else:
-            new_params, losses = dpsgd_masked_step(
+            new_params, losses = masked_step(
                 loss_fn, inner, batch, w, active, config)
             new_res = None
         if watchdog:
@@ -226,6 +239,7 @@ def train_on_traces(
     payload: QuantConfig = _NO_PAYLOAD,
     active_seq=None,
     watchdog: bool = False,
+    mesh=None,
 ):
     """``train_on_trace`` vmapped over a leading Monte-Carlo axis.
 
@@ -238,7 +252,7 @@ def train_on_traces(
         def one(p, w, live, b):
             return train_on_trace(loss_fn, p, w, live, b, config,
                                   snapshot_rounds, unroll, payload,
-                                  watchdog=watchdog)
+                                  watchdog=watchdog, mesh=mesh)
         axes = (0 if params_batched else None, 0, 0, 0)
         return jax.vmap(one, in_axes=axes)(
             node_params, w_seq, live_seq, batch_seq)
@@ -246,7 +260,7 @@ def train_on_traces(
     def one(p, w, live, act, b):
         return train_on_trace(loss_fn, p, w, live, b, config,
                               snapshot_rounds, unroll, payload, active_seq=act,
-                              watchdog=watchdog)
+                              watchdog=watchdog, mesh=mesh)
 
     axes = (0 if params_batched else None, 0, 0, 0, 0)
     return jax.vmap(one, in_axes=axes)(
@@ -459,6 +473,19 @@ def _replicate_family(inits: PyTree, n_nodes: int) -> PyTree:
     return jax.vmap(lambda p: replicate(p, n_nodes))(inits)
 
 
+def _exchange_mesh(mesh, n_nodes: int, payload: QuantConfig):
+    """``mesh`` where its fleet axes shard the node axis, so that the
+    D-PSGD step mixes by ``core.dpsgd.exchange_mix``: more than one fleet
+    slot, dividing ``n_nodes`` (the rule of
+    ``train.shardings.node_param_specs``), and an exact payload (the
+    compressed mixes keep the dense path). Otherwise None, and the step
+    mixes by the dense W matmul."""
+    if mesh is None or payload.mode != "none":
+        return None
+    fleet = fleet_size(mesh)
+    return mesh if fleet > 1 and n_nodes % fleet == 0 else None
+
+
 def _shard_family(inits: PyTree, n_nodes: int, batches: PyTree, mesh):
     """Lay the (S,)-batched family out on ``mesh``: node-parameters take
     ``train.shardings.node_param_specs`` with the Monte-Carlo axis
@@ -483,8 +510,8 @@ def _shard_family(inits: PyTree, n_nodes: int, batches: PyTree, mesh):
     params0 = jax.jit(_replicate_family, static_argnums=1,
                       out_shardings=shardings)(inits, n_nodes)
 
-    node_axes = tuple(a for a in mesh.axis_names if a != "model")
-    fleet = int(np.prod([mesh.shape[a] for a in node_axes], dtype=np.int64))
+    node_axes = replica_axes(mesh)
+    fleet = fleet_size(mesh)
     node_entry = node_axes if len(node_axes) > 1 else node_axes[0]
 
     def _shard_batch(b):
@@ -523,7 +550,9 @@ def train_model_on_traces(
     ``launch.mesh.make_fleet_mesh``) lays node-parameters out via
     ``train.shardings.node_param_specs`` before the compiled call, so the
     scan carry stays sharded — node count scales over the fleet axes,
-    model size over 'model', independently.
+    model size over 'model', independently. Where the fleet axes shard the
+    node axis, the rounds mix by ``core.dpsgd.exchange_mix``, else by the
+    dense W matmul.
 
     Returns ``(traces, out)`` like ``train_cnn_on_traces``: masked mean
     ``losses`` (S, rounds), eval-round metrics ``acc`` (S, E) with
@@ -532,21 +561,25 @@ def train_model_on_traces(
     watchdog ``rollbacks``.
 
     The call is the host span ``repro.train`` (``traces``, ``rounds``,
-    ``nodes``), in three parts: ``repro.train.prep`` (batches, initial
-    parameters, their replication and the uploads), ``repro.train.run``
-    (the compiled call and the losses' readback) and ``repro.train.post``
-    (masked means, eval, compaction)."""
+    ``nodes``, and ``mix``: ``"exchange"`` or ``"dense"``), in three
+    parts: ``repro.train.prep`` (batches, initial parameters, their
+    replication and the uploads), ``repro.train.run`` (the compiled call
+    and the losses' readback) and ``repro.train.post`` (masked means,
+    eval, compaction)."""
     cfgs = [get_scenario(c) if isinstance(c, str) else c for c in configs]
     if not cfgs:
         raise ValueError("train_model_on_traces needs at least one config")
+    mix_mesh = _exchange_mesh(mesh, cfgs[0].n_nodes, cfgs[0].payload)
     with span("train", traces=len(cfgs), rounds=int(n_rounds),
-              nodes=cfgs[0].n_nodes):
+              nodes=cfgs[0].n_nodes,
+              mix="dense" if mix_mesh is None else "exchange"):
         return _train_model_on_traces(adapter, cfgs, n_rounds, eta,
-                                      trace_batch, unroll, engine, mesh)
+                                      trace_batch, unroll, engine, mesh,
+                                      mix_mesh)
 
 
 def _train_model_on_traces(adapter, cfgs, n_rounds, eta, trace_batch, unroll,
-                           engine, mesh):
+                           engine, mesh, mix_mesh):
     """The body of ``train_model_on_traces``."""
     from ..checkpoint.ckpt import compact_nodes
 
@@ -616,7 +649,7 @@ def _train_model_on_traces(adapter, cfgs, n_rounds, eta, trace_batch, unroll,
             adapter.loss_fn, params0, w_seq, live_seq, batches,
             DPSGDConfig(eta=eta), snapshot_rounds=snapshot_rounds,
             params_batched=True, unroll=unroll, payload=payload,
-            active_seq=active_seq, watchdog=watchdog)
+            active_seq=active_seq, watchdog=watchdog, mesh=mix_mesh)
         raw = np.asarray(out_arrays[1], dtype=np.float64)  # (S, rounds, n)
     with span("train.post"):
         finals = out_arrays[0]

@@ -9,7 +9,8 @@ realizes a fading trace, and runs train-on-trace three ways:
 1. the per-round reference loop (``train_on_trace_reference``) — the oracle;
 2. the jitted scan with node-parameters laid out over a
    ``launch.mesh.make_fleet_mesh`` (``train.shardings.node_param_specs``),
-   asserting the final parameters actually span >= 2 devices;
+   mixing by ``core.dpsgd.exchange_mix`` where the node axis divides over
+   the fleet, asserting the final parameters actually span >= 2 devices;
 3. the full ``train_model_on_traces`` driver on the same mesh.
 
 All three must agree to the parity bound (<=1e-5 on final params and
@@ -42,8 +43,9 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
     from ..core.dpsgd import DPSGDConfig
     from ..launch.mesh import make_fleet_mesh
     from ..train.shardings import node_param_specs
-    from .batch import (train_model_on_traces, train_on_trace,
-                        train_on_trace_reference, transformer_adapter)
+    from .batch import (_exchange_mesh, train_model_on_traces,
+                        train_on_trace, train_on_trace_reference,
+                        transformer_adapter)
     from .scenario import get_scenario
     from .trace import precompute_traces
 
@@ -79,10 +81,12 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
             if b.shape[1] % fleet == 0
             else NamedSharding(mesh, P())),
         batches)
+    mix_mesh = _exchange_mesh(mesh, cfg.n_nodes, cfg.payload)
     final, losses = train_on_trace(
         adapter.loss_fn, p0_sharded, jnp.asarray(tr.w_eff),
         jnp.asarray(tr.live), b_sharded, config, unroll=1,
-        payload=cfg.payload, active_seq=jnp.asarray(tr.active))
+        payload=cfg.payload, active_seq=jnp.asarray(tr.active),
+        mesh=mix_mesh)
     device_span = {d.id for leaf in jax.tree.leaves(final)
                    for d in leaf.sharding.device_set}
     # leaves split across devices (replicated leaves span every device too)
@@ -113,6 +117,7 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
         "rounds": rounds,
         "n_nodes": cfg.n_nodes,
         "mesh": {"fleet": fleet, "model": model},
+        "mix": "dense" if mix_mesh is None else "exchange",
         "devices_visible": jax.device_count(),
         "devices_spanned": len(device_span),
         "sharded_leaves": sharded_leaves,

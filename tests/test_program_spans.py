@@ -3,6 +3,11 @@
 documented, present in the compiled step's op metadata, and without effect
 on any output."""
 import glob
+import json
+import os
+import subprocess
+import sys
+import textwrap
 from functools import partial
 
 import numpy as np
@@ -38,7 +43,7 @@ ARGS = {
     "repro.plan.solve": {"method"},
     "repro.plan.screen": {"candidates", "n"},
     "repro.scan": {"seed", "n", "rounds", "packets"},
-    "repro.train": {"traces", "rounds", "nodes"},
+    "repro.train": {"traces", "rounds", "nodes", "mix"},
 }
 FADING = {"fading.shadowing_sigma_db": 0.0}
 
@@ -124,7 +129,7 @@ def test_spans_carry_their_arguments(profiled):
     assert scan == {"seed": 5, "n": 16, "rounds": 4,
                     "packets": -(-int(cfg.model_bits) // cfg.mac.packet_bits)}
     (*_, train, _), = _spans(host, "repro.train")
-    assert train == {"traces": 1, "rounds": 2, "nodes": 2}
+    assert train == {"traces": 1, "rounds": 2, "nodes": 2, "mix": "dense"}
 
 
 def test_each_certification_is_one_span_inside_a_certified_sweep(profiled):
@@ -181,3 +186,47 @@ def test_step_scopes_reach_the_compiled_program(step, scopes):
     text = lowered.compile().as_text()
     for scope in scopes:
         assert f"/{scope}/" in text, scope
+
+
+def test_train_span_says_exchange_on_a_fleet_mesh(tmp_path):
+    """Over a mesh whose fleet axis shards the node axis the ``repro.train``
+    span carries ``mix="exchange"``; over the same mesh with a node count
+    that does not divide the fleet, ``mix="dense"``. Four host devices, in
+    a subprocess: this process keeps seeing one."""
+    code = textwrap.dedent(f"""
+        import glob, json
+        import jax
+        from repro.launch.mesh import make_fleet_mesh
+        from repro.sim import get_scenario
+        from repro.sim.batch import train_model_on_traces
+        from repro.sim.trace import precompute_trace, stack_traces
+        from test_program_spans import LINEAR
+
+        mesh = make_fleet_mesh(4, 1)
+        jax.profiler.start_trace({str(tmp_path)!r})
+        for n in (4, 6):
+            cfg = get_scenario("static", n_nodes=n, seed=7)
+            traces = stack_traces([precompute_trace(cfg, 2)])
+            _, out = train_model_on_traces(LINEAR, [cfg], 2,
+                                           trace_batch=traces, unroll=1,
+                                           mesh=mesh)
+            jax.block_until_ready(out["final_params"])
+        jax.profiler.stop_trace()
+        path, = glob.glob({str(tmp_path)!r} + "/plugins/profile/*/*.xplane.pb")
+        data = jax.profiler.ProfileData.from_file(path)
+        print(json.dumps(sorted(
+            (dict(ev.stats)["nodes"], dict(ev.stats)["mix"])
+            for plane in data.planes for line in plane.lines
+            for ev in line.events if ev.name == "repro.train")))
+    """)
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(here), "src"), here])
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=600)
+    assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
+    spans = json.loads(out.stdout.strip().splitlines()[-1])
+    assert spans == [[4, "exchange"], [6, "dense"]]

@@ -412,6 +412,7 @@ def test_sharded_transformer_smoke_subprocess():
     """)
     report = json.loads(out.strip().splitlines()[-1])
     assert report["ok"], report
+    assert report["mix"] == "exchange"
     assert report["devices_spanned"] >= 2
     assert report["parity"]["sharded_vs_reference_params"] <= 1e-5
     assert report["parity"]["driver_vs_reference_params"] <= 1e-5
