@@ -9,18 +9,25 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-__all__ = ["MoEConfig", "MLAConfig", "RWKVConfig", "RGLRUConfig", "ModelConfig",
-           "ShapeConfig", "RunConfig", "SHAPES", "reduce_for_smoke"]
+__all__ = ["MoEConfig", "MLAConfig", "RopeScaling", "RWKVConfig", "RGLRUConfig",
+           "ModelConfig", "ShapeConfig", "RunConfig", "SHAPES", "reduce_for_smoke"]
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int            # routed experts
+    n_experts: int            # routed experts: the router's width
     top_k: int
     d_ff_expert: int
     n_shared: int = 0         # shared (always-on) experts
-    capacity_factor: float = 1.25
-    router_noise: float = 0.0
+    norm_topk: bool = True    # renormalize the top-k gates to sum to 1
+    # the experts this chip holds under expert parallelism: experts
+    # [expert_offset, expert_offset + experts_held); 0 holds all of them
+    experts_held: int = 0
+    expert_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +36,17 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +82,7 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 1e4
     rope_fraction: float = 1.0
+    rope_scaling: Optional[RopeScaling] = None
     mlp_kind: str = "swiglu"  # swiglu | geglu | gelu | relu2
     norm: str = "rmsnorm"     # rmsnorm | layernorm
     tie_embeddings: bool = True
@@ -154,7 +173,8 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     moe = None
     if cfg.moe is not None:
         moe = dataclasses.replace(cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2),
-                                  d_ff_expert=64, n_shared=min(cfg.moe.n_shared, 1))
+                                  d_ff_expert=64, n_shared=min(cfg.moe.n_shared, 1),
+                                  experts_held=0, expert_offset=0)
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",

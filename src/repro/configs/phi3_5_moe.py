@@ -2,7 +2,10 @@
 
 32L, d_model 4096, 32 heads (GQA kv=8, head_dim 128), vocab 32064,
 MoE: 16 experts, top-2, expert d_ff 6400, SwiGLU experts, LayerNorm,
-untied head. Expert dim sharded over the model axis (1 expert/rank @TP16)."""
+untied head. Every chip of a replica holds all 16 experts: the expert
+tensors replicate over the model axis (``train/shardings.py``), and a chip's
+expert share is ``MoEConfig.experts_held``, which no deployment of this
+configuration cuts yet."""
 from .base import ModelConfig, MoEConfig
 
 CONFIG = ModelConfig(
@@ -18,10 +21,10 @@ CONFIG = ModelConfig(
     pattern=("global",),
     mlp_kind="swiglu",
     norm="layernorm",
-    moe=MoEConfig(n_experts=16, top_k=2, d_ff_expert=6400, n_shared=0,
-                  capacity_factor=1.25),
+    moe=MoEConfig(n_experts=16, top_k=2, d_ff_expert=6400, n_shared=0),
     tie_embeddings=False,
-    # 42B params: fp32 master + grads would exceed 16 GB/chip at TP=16;
-    # bf16 params keep the Mode B state at ~10.5 GB/chip (DESIGN.md §7).
+    # 42B params, 40B of them in the experts: bf16 halves the state, but
+    # replicated experts fit a 16 GB chip only in a deployment that holds a
+    # share of them
     param_dtype="bfloat16",
 )

@@ -64,14 +64,17 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool = True,
                       q_positions: Optional[jax.Array] = None,
                       k_positions: Optional[jax.Array] = None,
-                      k_chunk: int = 1024) -> jax.Array:
+                      k_chunk: int = 1024,
+                      scale: Optional[float] = None) -> jax.Array:
     """(B,S,Hq,Dqk) x (B,T,Hkv,Dqk), (B,T,Hkv,Dv) -> (B,S,Hq,Dv); online
-    softmax over KV blocks. Dv may differ from Dqk (MLA)."""
+    softmax over KV blocks. Dv may differ from Dqk (MLA). ``scale``
+    multiplies the scores; None is ``Dqk**-0.5``."""
     b, s, hq, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     dv = v.shape[-1]
     g = hq // hkv
-    qg = q.reshape(b, s, hkv, g, d).astype(jnp.float32) * d**-0.5
+    qg = q.reshape(b, s, hkv, g, d).astype(jnp.float32) * (
+        d**-0.5 if scale is None else scale)
     if q_positions is None:
         q_positions = jnp.arange(s)
     if k_positions is None:

@@ -8,13 +8,15 @@ Conventions:
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["dense_init", "dense", "norm_init", "norm", "mlp_init", "mlp",
-           "embed_init", "rope", "cross_entropy"]
+           "embed_init", "rope", "yarn", "cross_entropy"]
 
 
 def _dtype(name: str):
@@ -99,10 +101,11 @@ def embed_init(key, vocab: int, d_model: int, dtype: str = "float32") -> dict:
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float,
-         fraction: float = 1.0) -> jax.Array:
+         fraction: float = 1.0, inv_freq: Optional[jax.Array] = None) -> jax.Array:
     """Rotary embedding on the trailing head_dim; ``positions`` broadcasts
     against x's leading dims (..., S, H, D). ``fraction`` < 1 rotates only the
-    first ``fraction * D`` channels (stablelm-style partial rotary)."""
+    first ``fraction * D`` channels (stablelm-style partial rotary).
+    ``inv_freq`` (D_rot / 2,) replaces the ``theta`` frequencies (YaRN)."""
     d = x.shape[-1]
     d_rot = int(d * fraction)
     d_rot -= d_rot % 2
@@ -110,13 +113,41 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
         return x
     xr, xp = x[..., :d_rot], x[..., d_rot:]
     half = d_rot // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = inv_freq
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
     ang = ang[..., None, :]  # broadcast over heads (..., S, 1, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = xr[..., :half].astype(jnp.float32), xr[..., half:].astype(jnp.float32)
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return jnp.concatenate([rotated.astype(x.dtype), xp], axis=-1)
+
+
+def yarn(rs, dim: int, theta: float) -> tuple[np.ndarray, float]:
+    """YaRN (arXiv:2309.00071) as DeepSeek-V2 applies it to ``dim`` rope
+    channels: the inverse frequencies, interpolated by ``rs.factor`` below
+    the correction range and kept above it, and the factor mscale^2 on the
+    softmax scale. Where ``mscale`` equals ``mscale_all_dim``, as published,
+    the rotation itself is not rescaled."""
+    if rs.mscale != rs.mscale_all_dim:
+        raise ValueError("YaRN with mscale != mscale_all_dim is not supported")
+    half = dim // 2
+    extra = theta ** (-np.arange(half, dtype=np.float64) * 2 / dim)
+    inter = extra / rs.factor
+
+    def corr(rotations):
+        return dim * math.log(rs.original_max_position
+                              / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(rs.beta_fast)), 0)
+    high = min(math.ceil(corr(rs.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp
+    inv_freq = inter * (1.0 - keep) + extra * keep
+    mscale = 0.1 * rs.mscale_all_dim * math.log(rs.factor) + 1.0 if rs.factor > 1 else 1.0
+    return inv_freq.astype(np.float32), mscale ** 2
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array,

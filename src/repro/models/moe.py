@@ -1,48 +1,40 @@
-"""Mixture-of-Experts MLP: token-choice top-k routing, shared experts,
-capacity-bounded sort-based dispatch.
+"""Mixture-of-Experts MLP: one chip's share of a token-choice expert layer.
 
-Dispatch strategy (DESIGN.md §7): tokens are *replicated* across the TP
-("model") axis inside a replica, so the argsort/scatter below is purely local;
-only the expert weight tensors are sharded (expert dim over the model axis =
-expert parallelism). The gather-back of expert outputs is the one collective
-XLA inserts (comparable to Megatron-MoE's combine all-gather). We use a
-sort-based capacity dispatch instead of the (T, E, C) one-hot einsum — the
-one-hot dispatch tensor at our shapes (T=32k, E=16, C=5k) would be ~2.6e9
-elements per replica; the sort path is O(T·k log) with an (E·C, d) buffer.
+Routing: the router ``x·W_r`` in float32 over all ``n_experts`` experts,
+a softmax, greedy top-k. The gates are renormalized to sum to 1 where
+``norm_topk`` is set, and kept as they are otherwise (DeepSeek-V2 publishes
+``norm_topk_prob: false``).
 
-Routing follows the standard token-choice recipe: softmax router in fp32,
-top-k, renormalized gates (DeepSeek-style), capacity factor with dropped
-tokens passing through the residual stream (their expert output is zero).
+Expert parallelism: the layer holds experts ``expert_offset ..
+expert_offset + held - 1`` and computes ``Σ_{e chosen, e held} g_e ·
+FFN_e(x)``, plus the shared experts, which every chip computes alike. What
+the experts held on other chips add is left out here: their chips compute
+it, and on one chip the layer runs without that exchange. A configuration
+that holds every expert (the default) computes the whole layer.
+
+Dispatch is drop-free: the (token, choice) pairs are sorted by expert, and
+the held experts' SwiGLU runs as grouped matmuls over them
+(``kernels/grouped_matmul.py``), sized by the pairs routed to the held
+experts and not by a capacity.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig, MoEConfig
+from ..kernels.grouped_matmul import grouped_matmul
 from .layers import dense_init, mlp, mlp_init
 
-__all__ = ["moe_init", "moe_apply", "moe_active_params"]
-
-
-def _wsc(x: jax.Array, *spec) -> jax.Array:
-    """Best-effort sharding constraint: keeps expert-major buffers sharded
-    over the 'model' axis (expert parallelism). No-op off-mesh (CPU tests).
-    Under the Mode B node-vmap the caller sets spmd_axis_name so the node
-    axis is prepended automatically."""
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+__all__ = ["moe_init", "moe_apply", "moe_route"]
 
 
 def moe_init(key, cfg: ModelConfig, mcfg: MoEConfig) -> dict:
-    ks = jax.random.split(key, 6)
-    e, d, f = mcfg.n_experts, cfg.d_model, mcfg.d_ff_expert
+    ks = jax.random.split(key, 5)
+    e, d, f = mcfg.held, cfg.d_model, mcfg.d_ff_expert
     scale = d**-0.5
     p = {
-        "router": dense_init(ks[0], d, e, dtype=cfg.param_dtype),
+        "router": dense_init(ks[0], d, mcfg.n_experts, dtype=cfg.param_dtype),
         "ew_gate": (jax.random.normal(ks[1], (e, d, f), jnp.float32) * scale).astype(cfg.param_dtype),
         "ew_up": (jax.random.normal(ks[2], (e, d, f), jnp.float32) * scale).astype(cfg.param_dtype),
         "ew_down": (jax.random.normal(ks[3], (e, f, d), jnp.float32) * f**-0.5).astype(cfg.param_dtype),
@@ -52,79 +44,40 @@ def moe_init(key, cfg: ModelConfig, mcfg: MoEConfig) -> dict:
     return p
 
 
-def _dispatch_row(xt, expert_idx, gate_vals, p, cfg, mcfg, cap):
-    """Capacity-bounded dispatch for ONE token row (t, d). Batched over the
-    leading (data-sharded) batch dim by vmap in moe_apply, so the
-    sort/scatter never crosses data shards (a global-token dispatch forced
-    XLA to all-reduce the full (E, C, d) expert buffer across the data axis —
-    195 GB/layer on deepseek prefill_32k; see EXPERIMENTS.md §Perf cell C)."""
-    dt = jnp.dtype(cfg.dtype)
-    t, d = xt.shape
-    e, k = mcfg.n_experts, mcfg.top_k
-
-    flat_expert = expert_idx.reshape(-1)                        # (t*k,)
-    flat_token = jnp.repeat(jnp.arange(t), k)
-    flat_gate = gate_vals.reshape(-1)
-
-    order = jnp.argsort(flat_expert)                            # group by expert
-    sorted_expert = flat_expert[order]
-    sorted_token = flat_token[order]
-    sorted_gate = flat_gate[order]
-    # position within the expert group = index - first index of that expert
-    pos_in_expert = jnp.arange(t * k) - jnp.searchsorted(
-        sorted_expert, sorted_expert, side="left")
-    keep = pos_in_expert < cap
-    dest = jnp.where(keep, sorted_expert * cap + pos_in_expert, e * cap)
-
-    buf = jnp.zeros((e * cap + 1, d), dt).at[dest].set(xt[sorted_token].astype(dt))
-    xe = buf[: e * cap].reshape(e, cap, d)
-    return xe, dest, sorted_token, sorted_gate, keep
+def moe_route(router_w: jax.Array, x: jax.Array, mcfg: MoEConfig
+              ) -> tuple[jax.Array, jax.Array]:
+    """(T, d) tokens -> (T, k) gates and chosen experts: float32 logits
+    over all experts, softmax, greedy top-k."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), mcfg.top_k)
+    if mcfg.norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, experts
 
 
 def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig, mcfg: MoEConfig) -> jax.Array:
-    """x: (B, S, d) -> (B, S, d). Dispatch is per batch row (data-local);
-    expert compute is batched over rows with the expert dim sharded over the
-    model axis (expert parallelism)."""
+    """x: (B, S, d) -> (B, S, d): the held experts' part of the routed
+    output plus the shared experts."""
     dt = jnp.dtype(cfg.dtype)
     b, s, d = x.shape
-    e, k = mcfg.n_experts, mcfg.top_k
-    # per-row capacity; floor lets small rows (decode steps) run drop-free so
-    # decode matches the teacher-forced forward.
-    cap = max(int(s * k * mcfg.capacity_factor / e), min(s, 64), k)
-
-    logits = jnp.einsum("bsd,de->bse", x, p["router"]["w"].astype(dt)
-                        ).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)             # (b, s, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    xe, dest, sorted_token, sorted_gate, keep = jax.vmap(
-        lambda xr, er, gr: _dispatch_row(xr, er, gr, p, cfg, mcfg, cap)
-    )(x, expert_idx, gate_vals)
-    xe = _wsc(xe, None, "model", None, None)                     # (b, E, C, d)
-
-    # per-expert SwiGLU, batched over rows (E sharded over the model axis)
-    gate = jnp.einsum("becd,edf->becf", xe, p["ew_gate"].astype(dt))
-    up = jnp.einsum("becd,edf->becf", xe, p["ew_up"].astype(dt))
-    h = jax.nn.silu(_wsc(gate, None, "model", None, None)) * up
-    h = _wsc(h, None, "model", None, None)
-    ye = jnp.einsum("becf,efd->becd", h, p["ew_down"].astype(dt))
-    ye = _wsc(ye, None, "model", None, None)
-
-    def combine_row(ye_r, dest_r, token_r, gate_r, keep_r):
-        ye_flat = jnp.concatenate([ye_r.reshape(e * cap, d),
-                                   jnp.zeros((1, d), dt)])
-        picked = ye_flat[dest_r] * (gate_r * keep_r).astype(dt)[:, None]
-        return jnp.zeros((s, d), dt).at[token_r].add(picked)
-
-    y = jax.vmap(combine_row)(ye, dest, sorted_token, sorted_gate, keep)
-
-    if "shared" in p:
-        y = y + mlp(p["shared"], x.astype(dt), "swiglu", dt)
-    return y
-
-
-def moe_active_params(cfg: ModelConfig, mcfg: MoEConfig) -> int:
-    """Per-layer active (per-token) MoE params: top-k + shared experts."""
-    per_expert = 3 * cfg.d_model * mcfg.d_ff_expert
-    return per_expert * (mcfg.top_k + mcfg.n_shared)
+    k = mcfg.top_k
+    xt = x.reshape(b * s, d)
+    with jax.named_scope("moe.route"):
+        gates, experts = moe_route(p["router"]["w"], xt, mcfg)
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat)                   # pairs grouped by expert
+        sizes = jnp.bincount(flat, length=mcfg.n_experts)
+        xs = xt.astype(dt)[order // k]
+    with jax.named_scope("moe.gmm"):
+        def grouped(a, w):
+            return grouped_matmul(a, p[w].astype(dt), sizes, mcfg.expert_offset)
+        h = jax.nn.silu(grouped(xs, "ew_gate")) * grouped(xs, "ew_up")
+        ys = grouped(h, "ew_down")
+    with jax.named_scope("moe.combine"):
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
+        y = jnp.einsum("tk,tkd->td", gates, ys[back].reshape(b * s, k, d))
+        y = y.astype(dt)
+        if "shared" in p:
+            y = y + mlp(p["shared"], xt.astype(dt), "swiglu", dt)
+    return y.reshape(b, s, d)

@@ -83,9 +83,11 @@ def _row_where(mask: jax.Array, a: PyTree, b: PyTree) -> PyTree:
     return jax.tree.map(_sel, a, b)
 
 
-@partial(jax.jit,
-         static_argnames=("loss_fn", "config", "snapshot_rounds", "unroll",
-                          "payload", "watchdog", "mesh"))
+_TRAIN_STATIC = ("loss_fn", "config", "snapshot_rounds", "unroll", "payload",
+                 "watchdog", "mesh")
+
+
+@partial(jax.jit, static_argnames=_TRAIN_STATIC)
 def train_on_trace(
     loss_fn: Callable[[PyTree, PyTree], Any],
     node_params: PyTree,
@@ -226,6 +228,14 @@ def train_on_trace(
     return (final,) + tuple(outs)
 
 
+# the same program, consuming its initial parameters: their buffers become
+# the scan's, so that ``train_on_traces`` holds one copy of the family's
+# parameters less
+_train_on_trace_donating = jax.jit(train_on_trace.__wrapped__,
+                                   static_argnames=_TRAIN_STATIC,
+                                   donate_argnames=("node_params",))
+
+
 def train_on_traces(
     loss_fn: Callable[[PyTree, PyTree], Any],
     node_params: PyTree,
@@ -246,21 +256,21 @@ def train_on_traces(
     Every array gains a leading (S,) axis (``TraceBatch`` layout). With
     ``params_batched`` the initial parameters carry the axis too (per-seed
     inits); otherwise one init is shared by every trace. One compiled call
-    produces the whole (S,)-family of loss/parameter trajectories.
+    produces the whole (S,)-family of loss/parameter trajectories. The call
+    consumes ``node_params``: the caller may not use them again.
     """
+    step = _train_on_trace_donating   # train_on_trace, donating node_params
     if active_seq is None:
         def one(p, w, live, b):
-            return train_on_trace(loss_fn, p, w, live, b, config,
-                                  snapshot_rounds, unroll, payload,
-                                  watchdog=watchdog, mesh=mesh)
+            return step(loss_fn, p, w, live, b, config, snapshot_rounds,
+                        unroll, payload, watchdog=watchdog, mesh=mesh)
         axes = (0 if params_batched else None, 0, 0, 0)
         return jax.vmap(one, in_axes=axes)(
             node_params, w_seq, live_seq, batch_seq)
 
     def one(p, w, live, act, b):
-        return train_on_trace(loss_fn, p, w, live, b, config,
-                              snapshot_rounds, unroll, payload, active_seq=act,
-                              watchdog=watchdog, mesh=mesh)
+        return step(loss_fn, p, w, live, b, config, snapshot_rounds, unroll,
+                    payload, active_seq=act, watchdog=watchdog, mesh=mesh)
 
     axes = (0 if params_batched else None, 0, 0, 0, 0)
     return jax.vmap(one, in_axes=axes)(
@@ -368,6 +378,8 @@ class ModelAdapter:
       ``ScenarioConfig.model_shapes`` so per-leaf payload framing charges
       exact wire bits; empty () keeps the config's flat accounting (the
       CNN instance does, preserving every pre-pytree trace bit-for-bit).
+    * ``experts_held`` — routed experts each expert layer holds on this
+      chip (0: the model has none); reported on the ``repro.train`` span.
     """
     name: str
     init_params: Callable[[int], PyTree]
@@ -376,6 +388,7 @@ class ModelAdapter:
     eval_fn: Optional[Callable[[PyTree], Any]] = None
     model_bits: float = 0.0
     param_shapes: tuple = ()
+    experts_held: int = 0
 
 
 def _cnn_adapter(shard_x: np.ndarray, shard_y: np.ndarray, batch: int,
@@ -464,7 +477,8 @@ def transformer_adapter(arch: str = "stablelm-3b", batch: int = 4,
     return ModelAdapter(
         name=mcfg.name, init_params=init_params, loss_fn=loss_fn,
         batch_fn=batch_fn, eval_fn=eval_fn, model_bits=model_bits,
-        param_shapes=leaf_shapes)
+        param_shapes=leaf_shapes,
+        experts_held=mcfg.moe.held if mcfg.moe is not None else 0)
 
 
 def _replicate_family(inits: PyTree, n_nodes: int) -> PyTree:
@@ -561,7 +575,8 @@ def train_model_on_traces(
     watchdog ``rollbacks``.
 
     The call is the host span ``repro.train`` (``traces``, ``rounds``,
-    ``nodes``, and ``mix``: ``"exchange"`` or ``"dense"``), in three
+    ``nodes``, ``mix``: ``"exchange"`` or ``"dense"``, and the adapter's
+    ``experts_held``), in three
     parts: ``repro.train.prep`` (batches, initial parameters, their
     replication and the uploads), ``repro.train.run`` (the compiled call
     and the losses' readback) and ``repro.train.post`` (masked means,
@@ -572,7 +587,8 @@ def train_model_on_traces(
     mix_mesh = _exchange_mesh(mesh, cfgs[0].n_nodes, cfgs[0].payload)
     with span("train", traces=len(cfgs), rounds=int(n_rounds),
               nodes=cfgs[0].n_nodes,
-              mix="dense" if mix_mesh is None else "exchange"):
+              mix="dense" if mix_mesh is None else "exchange",
+              experts_held=adapter.experts_held):
         return _train_model_on_traces(adapter, cfgs, n_rounds, eta,
                                       trace_batch, unroll, engine, mesh,
                                       mix_mesh)

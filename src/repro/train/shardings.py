@@ -29,10 +29,12 @@ _W_RULES: dict[str, tuple] = {
     "wo": ("model", None),
     # mlp
     "w_up": (None, "model"), "w_gate": (None, "model"), "w_down": ("model", None),
-    # moe
+    # moe: the experts a chip holds are ``MoEConfig.experts_held`` (its
+    # ``expert_offset`` onwards), never a sharding; the grouped matmul is a
+    # custom call that GSPMD cannot split, so the expert tensors replicate
     "router": (None, None),
-    "ew_gate": ("model", None, None), "ew_up": ("model", None, None),
-    "ew_down": ("model", None, None),
+    "ew_gate": (None, None, None), "ew_up": (None, None, None),
+    "ew_down": (None, None, None),
     "shared": None,  # handled by nested w_up/w_gate/w_down
     # mla
     "wkv_a": (None, None), "w_uk": (None, "model"), "w_uv": (None, "model"),

@@ -81,8 +81,16 @@ def test_moe_configs():
     ds = get_config("deepseek-v2-lite-16b")
     assert ds.moe.n_experts == 64 and ds.moe.top_k == 6 and ds.moe.n_shared == 2
     assert ds.first_k_dense == 1 and ds.mla.kv_lora_rank == 512
+    # the published config.json: untied head, gates not renormalized,
+    # YaRN rope scaling; every expert held unless a deployment cuts it
+    assert not ds.tie_embeddings and not ds.moe.norm_topk
+    assert ds.moe.held == 64 and ds.moe.expert_offset == 0
+    rs = ds.rope_scaling
+    assert (rs.factor, rs.original_max_position, rs.beta_fast, rs.beta_slow,
+            rs.mscale, rs.mscale_all_dim) == (40, 4096, 32, 1, 0.707, 0.707)
     phi = get_config("phi3.5-moe-42b-a6.6b")
     assert phi.moe.n_experts == 16 and phi.moe.top_k == 2
+    assert phi.moe.norm_topk and phi.rope_scaling is None
 
 
 def test_pattern_structures():

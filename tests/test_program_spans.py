@@ -43,7 +43,7 @@ ARGS = {
     "repro.plan.solve": {"method"},
     "repro.plan.screen": {"candidates", "n"},
     "repro.scan": {"seed", "n", "rounds", "packets"},
-    "repro.train": {"traces", "rounds", "nodes", "mix"},
+    "repro.train": {"traces", "rounds", "nodes", "mix", "experts_held"},
 }
 FADING = {"fading.shadowing_sigma_db": 0.0}
 
@@ -129,7 +129,8 @@ def test_spans_carry_their_arguments(profiled):
     assert scan == {"seed": 5, "n": 16, "rounds": 4,
                     "packets": -(-int(cfg.model_bits) // cfg.mac.packet_bits)}
     (*_, train, _), = _spans(host, "repro.train")
-    assert train == {"traces": 1, "rounds": 2, "nodes": 2, "mix": "dense"}
+    assert train == {"traces": 1, "rounds": 2, "nodes": 2, "mix": "dense",
+                     "experts_held": 0}
 
 
 def test_each_certification_is_one_span_inside_a_certified_sweep(profiled):

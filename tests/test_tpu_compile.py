@@ -92,6 +92,26 @@ def test_flash_attention_gqa_compiles(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def test_grouped_matmul_compiles_under_the_node_vmap(one_chip):
+    """DeepSeek-V2-Lite's expert gate projection for two nodes of 1024
+    tokens (6144 pairs sorted over 64 experts, 8 held), forward and
+    backward: the three products compile to Pallas kernels under their own
+    names, which the benchmark's trace reader looks for."""
+    from repro.kernels.grouped_matmul import KERNEL_NAMES, grouped_matmul
+
+    def loss(x, w, sizes):
+        return jnp.sin(grouped_matmul(x, w, sizes, 0, interpret=False).astype(
+            jnp.float32)).sum()
+
+    hlo = _compile(lambda x, w, s: jax.vmap(jax.grad(loss, (0, 1)))(x, w, s),
+                   _shape((2, 6144, 2048), jnp.bfloat16, one_chip),
+                   _shape((2, 8, 2048, 1408), jnp.bfloat16, one_chip),
+                   _shape((2, 64), jnp.int32, one_chip))
+    calls = [line.split("=")[0].strip().lstrip("%") for line in hlo.splitlines()
+             if "tpu_custom_call" in line and "=" in line]
+    assert {c.split(".")[0] for c in calls} == set(KERNEL_NAMES), calls
+
+
 def test_static_channel_scan_compiles(one_chip):
     """The jitted TDM round loop of the static world at n = 16, traced
     under x64 as ``precompute_trace_scan`` traces it."""
