@@ -6,15 +6,17 @@ State layout: every parameter leaf carries a leading **node axis** of size n
     X_{k+1} <- W @ X_k  -  eta * stack_i( grad F_i(x_{k,i}; xi_{k,i}) )
 
 One step = (a) per-node minibatch gradients via ``jax.vmap`` over the node
-axis, (b) mixing with the averaging matrix W, (c) SGD update. Mixing takes
-one of two paths. Where every node sits on one device (the unsharded steps,
-the CNN sweeps, a node axis that does not divide over a mesh's fleet axes,
-the compressed mixes), ``mix`` is a dense fp32 ``W @ X`` matmul. Where a
-mesh's fleet axes shard the node axis, ``exchange_mix`` computes the same
-W X inside ``shard_map``: each chip gathers every chip's block of node
-rows and combines them elementwise with its own rows of W, fused with the
-update, in place of a matmul over the gathered node axis and a separate
-update pass.
+axis, (b) mixing with the averaging matrix W, (c) SGD update. Both mixing
+paths share one node-order combine (``_node_combine``): every output row
+is the fp32 sum, in node-index order, of ``W[i, j] * x_j``, elementwise on
+the leaves as they are laid out, and the update joins the combine's
+pass. Where a mesh's fleet axes shard the node axis,
+``exchange_mix`` runs it inside ``shard_map`` on every chip's gathered
+blocks. Where every node sits on one device (the unsharded steps, the CNN
+sweeps, a node axis that does not divide over a mesh's fleet axes),
+``mix`` runs it on the local stack up to ``COMBINE_MAX_NODES`` nodes, and
+past that multiplies by W as one fp32 matmul over the node axis; the
+compressed mixes keep their own matmul.
 This runs the *mathematics* of n wireless nodes exactly on one host; the
 wall-clock communication cost is modeled separately by ``comm_model.tdm_time_s``
 (exactly how the paper itself evaluates runtime: measured compute + Eq. 3).
@@ -36,7 +38,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..launch.mesh import fleet_size, replica_axes
 
-__all__ = ["DPSGDConfig", "replicate", "mix", "exchange_mix", "dpsgd_step",
+__all__ = ["DPSGDConfig", "replicate", "mix", "mix_path",
+           "COMBINE_MAX_NODES", "exchange_mix", "dpsgd_step",
            "make_dpsgd_step", "dpsgd_masked_step", "make_dpsgd_masked_step",
            "dpsgd_masked_compressed_step",
            "make_dpsgd_compressed_step", "embed_w", "zero_residuals",
@@ -94,16 +97,79 @@ def replicate(params: PyTree, n: int) -> PyTree:
 # (an identity W row would not return its node's parameters verbatim).
 _MIX_PRECISION = jax.lax.Precision.HIGHEST
 
+# The most nodes whose one-device mix is the node-order combine. Up to 8
+# the node axis fills at most one float32 (8, 128) tile's sublanes, so a W
+# matmul over it leaves the MXU empty and only relays every leaf to
+# (n, -1) and back; past it W's contraction starts to feed the MXU, while
+# the combine's n^2 multiply-adds would not. Set from that tiling; the
+# chip measurement of mix + update at n = 2, 4, 8, 16 that should confirm
+# it is still to be made (PERF.md, section 7).
+COMBINE_MAX_NODES = 8
+
+
+def mix_path(n: int) -> str:
+    """How ``mix`` combines ``n`` nodes on one device: ``"combine"``
+    (``n <= COMBINE_MAX_NODES``) or ``"dense"`` (the W matmul)."""
+    return "combine" if n <= COMBINE_MAX_NODES else "dense"
+
+
+def _node_combine(rows: jax.Array, node: Callable[[int], jax.Array],
+                  rank: int, dtype) -> jax.Array:
+    """W's rows ``rows`` (b, n) applied to the nodes ``node(0)`` ..
+    ``node(n - 1)``, each of rank ``rank``: every output row is the sum over
+    the nodes j, in node-index order, of ``rows[:, j] * node(j)``, as
+    float32 multiply-adds cast back to ``dtype``. The leaves keep their
+    layout and W stays runtime data; an identity row returns its node bit
+    for bit, and what follows elementwise (the update) fuses with it."""
+    col = (rows.shape[0],) + (1,) * rank
+    acc = None
+    for j in range(rows.shape[1]):
+        term = rows[:, j].reshape(col) * node(j).astype(jnp.float32)[None]
+        acc = term if acc is None else acc + term
+    return acc.astype(dtype)
+
 
 def mix(node_params: PyTree, w: jax.Array) -> PyTree:
     """X <- W @ X on the leading node axis of every leaf, under the
-    ``dpsgd.mix`` name scope."""
+    ``dpsgd.mix`` name scope, where every node sits on one device.
+
+    Up to ``COMBINE_MAX_NODES`` nodes this is ``exchange_mix``'s node-order
+    combine over the local stack, one output row at a time, the rows
+    joined: the leaves keep their layout, and the compiler forms every row
+    of a leaf in one pass over its nodes. Past it, a float32 ``HIGHEST``
+    matmul of W with every leaf flattened to (n, -1). ``mix_path`` names
+    the path; ``_mix_update`` adds the step's update."""
     def _mix(leaf: jax.Array) -> jax.Array:
-        flat = leaf.reshape(leaf.shape[0], -1)
-        return jnp.matmul(w.astype(flat.dtype), flat,
-                          precision=_MIX_PRECISION).reshape(leaf.shape)
+        n = leaf.shape[0]
+        if mix_path(n) == "dense":
+            flat = leaf.reshape(n, -1)
+            return jnp.matmul(w.astype(flat.dtype), flat,
+                              precision=_MIX_PRECISION).reshape(leaf.shape)
+        w32 = w.astype(jnp.float32)
+        return jnp.concatenate([
+            _node_combine(w32[i:i + 1], lambda j: leaf[j], leaf.ndim - 1,
+                          leaf.dtype) for i in range(n)])
     with jax.named_scope("dpsgd.mix"):
         return jax.tree.map(_mix, node_params)
+
+
+def _mix_update(node_params: PyTree, w: jax.Array, grads: PyTree,
+                eta: float) -> PyTree:
+    """W X - eta G on one device: ``_sgd(mix(X, W), G)``, where ``mix``
+    combines, with each node's row updated on its own and the rows joined.
+    The compiler then forms each row with its update in one pass and
+    writes the joined rows, the next state, whole, as it writes a matmul's
+    output: a state formed anew inside each of its consumers instead may
+    round differently in each (XLA:CPU contracts multiply-adds per
+    fusion)."""
+    def _update(m: jax.Array, g: jax.Array) -> jax.Array:
+        n = m.shape[0]
+        if mix_path(n) == "dense":
+            return _sgd(m, g, eta)
+        with jax.named_scope("dpsgd.update"):
+            return jnp.concatenate([_sgd(m[i:i + 1], g[i:i + 1], eta)
+                                    for i in range(n)])
+    return jax.tree.map(_update, mix(node_params, w), grads)
 
 
 def exchange_mix(node_params: PyTree, w: jax.Array, mesh,
@@ -116,15 +182,16 @@ def exchange_mix(node_params: PyTree, w: jax.Array, mesh,
 
     Chip c holds the b = n / F node rows c*b .. c*b + b - 1. One
     ``all_gather`` per leaf over the fleet axes brings it every chip's
-    block, and its output rows are the sum over every node i, in node index
-    order, of ``W[rows_c, i] * x_i``: elementwise fp32 multiply-adds with
-    the weights sliced from the traced W, so W stays runtime data, an
-    identity row returns its node's parameters bit for bit, and the
-    combine and the update are one elementwise pass that XLA fuses, in
-    place of a matmul over the node axis and a separate update. The
-    exchange and the combine run under the ``dpsgd.mix`` name scope, the
-    update under ``dpsgd.update``. ``mix`` computes the same result where
-    all nodes sit on one device.
+    block, and ``_node_combine`` forms its output rows from them: the sum
+    over every node i, in node index order, of ``W[rows_c, i] * x_i``, as
+    elementwise fp32 multiply-adds with the weights sliced from the traced
+    W, so W stays runtime data, an identity row returns its node's
+    parameters bit for bit, and the combine and the update are one
+    elementwise pass that XLA fuses, in place of a matmul over the node
+    axis and a separate update. The exchange and the combine run under the
+    ``dpsgd.mix`` name scope, the update under ``dpsgd.update``. ``mix``
+    runs the same combine where all nodes sit on one device, so up to
+    ``COMBINE_MAX_NODES`` nodes the two agree bit for bit.
 
     Why a gather and not ``ppermute`` shifts: every node hears every other
     in a dense W, so each chip receives F - 1 blocks either way, and on a
@@ -151,14 +218,8 @@ def exchange_mix(node_params: PyTree, w: jax.Array, mesh,
         def _mix(x: jax.Array) -> jax.Array:
             # (F, b, ...): every chip's block, in node-index order
             xs = jax.lax.all_gather(x, axis, axis=0, tiled=False)
-            col = (b,) + (1,) * (x.ndim - 1)
-            acc = None
-            for j in range(fleet):
-                for m in range(b):
-                    term = (rows[:, j * b + m].reshape(col)
-                            * xs[j, m].astype(jnp.float32)[None])
-                    acc = term if acc is None else acc + term
-            return acc.astype(x.dtype)
+            return _node_combine(rows, lambda i: xs[i // b, i % b],
+                                 x.ndim - 1, x.dtype)
 
         with jax.named_scope("dpsgd.mix"):
             mixed = jax.tree.map(_mix, x_tree)
@@ -225,7 +286,7 @@ def dpsgd_step(
     if h == 1:
         losses, grads = _node_grads(loss_fn, node_params, node_batches)
         if config.mix_first:
-            new_params = _sgd(mix(node_params, w), grads, config.eta)
+            new_params = _mix_update(node_params, w, grads, config.eta)
         else:
             # gradient-first order: X <- W (X - eta G). The previous
             # implementation skipped W entirely here, silently degenerating
@@ -286,7 +347,7 @@ def dpsgd_masked_step(
 
     ``mesh``, when given, is a mesh whose fleet axes shard the node axis
     (``train.shardings.node_param_specs``): W X is then ``exchange_mix``,
-    fused with the update, in place of the dense ``mix``.
+    fused with the update, in place of the one-device ``mix``.
     """
     if config.local_steps != 1:
         raise NotImplementedError(
@@ -300,7 +361,7 @@ def dpsgd_masked_step(
         return exchange_mix(_sgd(node_params, grads, config.eta), w,
                             mesh), losses
     if config.mix_first:
-        new_params = _sgd(mix(node_params, w), grads, config.eta)
+        new_params = _mix_update(node_params, w, grads, config.eta)
     else:
         new_params = mix(_sgd(node_params, grads, config.eta), w)
     return new_params, losses

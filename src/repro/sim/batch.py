@@ -40,8 +40,8 @@ from ..core.compression import QuantConfig
 from ..launch.mesh import fleet_size, replica_axes
 from ..utils.spans import span
 from ..core.dpsgd import (DPSGDConfig, dpsgd_masked_compressed_step,
-                          dpsgd_masked_step, node_axis_size, replicate,
-                          zero_residuals)
+                          dpsgd_masked_step, mix_path, node_axis_size,
+                          replicate, zero_residuals)
 from .scenario import ScenarioConfig, get_scenario
 from .trace import (TraceBatch, TrainTrace, driver_batch_indices,
                     model_batch_tokens, precompute_traces)
@@ -144,8 +144,8 @@ def train_on_trace(
 
     ``mesh``, when given, is the mesh whose fleet axes shard the node axis
     of ``node_params`` (see ``_exchange_mesh``): each round then mixes by
-    ``core.dpsgd.exchange_mix`` in place of the dense W matmul. The payload
-    must then be exact.
+    ``core.dpsgd.exchange_mix`` in place of the one-device ``mix``. The
+    payload must then be exact.
     """
     if payload.mode == "auto":
         raise ValueError(
@@ -493,11 +493,20 @@ def _exchange_mesh(mesh, n_nodes: int, payload: QuantConfig):
     slot, dividing ``n_nodes`` (the rule of
     ``train.shardings.node_param_specs``), and an exact payload (the
     compressed mixes keep the dense path). Otherwise None, and the step
-    mixes by the dense W matmul."""
+    mixes on one device by ``core.dpsgd.mix``."""
     if mesh is None or payload.mode != "none":
         return None
     fleet = fleet_size(mesh)
     return mesh if fleet > 1 and n_nodes % fleet == 0 else None
+
+
+def _mix_kind(mix_mesh, n_nodes: int, payload: QuantConfig) -> str:
+    """What the D-PSGD step mixes by: ``"exchange"`` over ``mix_mesh``
+    (``_exchange_mesh``), else ``core.dpsgd.mix_path``'s ``"combine"`` or
+    ``"dense"``; the compressed mixes keep their W matmul (``"dense"``)."""
+    if mix_mesh is not None:
+        return "exchange"
+    return mix_path(n_nodes) if payload.mode == "none" else "dense"
 
 
 def _shard_family(inits: PyTree, n_nodes: int, batches: PyTree, mesh):
@@ -565,8 +574,8 @@ def train_model_on_traces(
     ``train.shardings.node_param_specs`` before the compiled call, so the
     scan carry stays sharded — node count scales over the fleet axes,
     model size over 'model', independently. Where the fleet axes shard the
-    node axis, the rounds mix by ``core.dpsgd.exchange_mix``, else by the
-    dense W matmul.
+    node axis, the rounds mix by ``core.dpsgd.exchange_mix``, else by
+    ``core.dpsgd.mix`` on one device.
 
     Returns ``(traces, out)`` like ``train_cnn_on_traces``: masked mean
     ``losses`` (S, rounds), eval-round metrics ``acc`` (S, E) with
@@ -575,7 +584,8 @@ def train_model_on_traces(
     watchdog ``rollbacks``.
 
     The call is the host span ``repro.train`` (``traces``, ``rounds``,
-    ``nodes``, ``mix``: ``"exchange"`` or ``"dense"``, and the adapter's
+    ``nodes``, ``mix``: ``"exchange"``, ``"combine"`` (the one-device
+    node-order combine) or ``"dense"`` (the W matmul), and the adapter's
     ``experts_held``), in three
     parts: ``repro.train.prep`` (batches, initial parameters, their
     replication and the uploads), ``repro.train.run`` (the compiled call
@@ -587,7 +597,7 @@ def train_model_on_traces(
     mix_mesh = _exchange_mesh(mesh, cfgs[0].n_nodes, cfgs[0].payload)
     with span("train", traces=len(cfgs), rounds=int(n_rounds),
               nodes=cfgs[0].n_nodes,
-              mix="dense" if mix_mesh is None else "exchange",
+              mix=_mix_kind(mix_mesh, cfgs[0].n_nodes, cfgs[0].payload),
               experts_held=adapter.experts_held):
         return _train_model_on_traces(adapter, cfgs, n_rounds, eta,
                                       trace_batch, unroll, engine, mesh,

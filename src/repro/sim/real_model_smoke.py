@@ -43,7 +43,7 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
     from ..core.dpsgd import DPSGDConfig
     from ..launch.mesh import make_fleet_mesh
     from ..train.shardings import node_param_specs
-    from .batch import (_exchange_mesh, train_model_on_traces,
+    from .batch import (_exchange_mesh, _mix_kind, train_model_on_traces,
                         train_on_trace, train_on_trace_reference,
                         transformer_adapter)
     from .scenario import get_scenario
@@ -117,7 +117,7 @@ def run(arch: str = "stablelm-3b", scenario: str = "fading", rounds: int = 4,
         "rounds": rounds,
         "n_nodes": cfg.n_nodes,
         "mesh": {"fleet": fleet, "model": model},
-        "mix": "dense" if mix_mesh is None else "exchange",
+        "mix": _mix_kind(mix_mesh, cfg.n_nodes, cfg.payload),
         "devices_visible": jax.device_count(),
         "devices_spanned": len(device_span),
         "sharded_leaves": sharded_leaves,

@@ -70,16 +70,20 @@ for fleet, model, b in CASES:
     w_dead = jnp.asarray(dpsgd.embed_w(np.asarray(stochastic(ids.size)), ids,
                                        n), jnp.float32)
     dead = ex(x, w_dead)
+    dense_upd = jax.jit(lambda x, w, g: dpsgd._mix_update(x, w, g, 0.05))
     out["cases"][f"{fleet}x{model}x{b}"] = {
         "mix_rel": rel(got, dpsgd.mix(x, w)),
+        "mix_exact": same(got, jax.jit(dpsgd.mix)(x, w)),
         "update_rel": rel(upd(x, w, g),
                           dpsgd._sgd(dpsgd.mix(x, w), g, 0.05)),
+        "update_exact": same(upd(x, w, g), dense_upd(x, w, g)),
         "wq_spec": str(got["wq"].sharding.spec),
         "identity_exact": same(ex(x, jnp.eye(n, dtype=jnp.float32)), x),
         "dead_rows_exact": all(np.array_equal(np.asarray(dead[k])[~live],
                                               np.asarray(x[k])[~live])
                                for k in x),
         "dead_live_rel": rel(dead, dpsgd.mix(x, w_dead)),
+        "dead_live_exact": same(dead, jax.jit(dpsgd.mix)(x, w_dead)),
     }
 
 # node-index order: 8 nodes as 4 chips x 2 rows and as 8 chips x 1 row
@@ -123,12 +127,14 @@ def group_size(line):
 
 
 mesh = make_fleet_mesh(4, 2)
-p = {"wq": jax.device_put(
-    jnp.ones((1, 4, 4, 8)),
-    NamedSharding(mesh, P(None, "fleet", None, "model")))}
-bt = {"x": jnp.ones((1, 3, 4, 2, 4)), "y": jnp.ones((1, 3, 4, 2, 8))}
-w, live = jnp.full((1, 3, 4, 4), 0.25), jnp.ones((1, 3, 4), bool)
-for name, m in (("exchange", mesh), ("dense", None)):
+# the exchange, and the one-device mix at and past COMBINE_MAX_NODES
+for name, m, n in (("exchange", mesh, 4), ("combine", None, 4),
+                   ("dense", None, 16)):
+    p = {"wq": jax.device_put(
+        jnp.ones((1, n, 4, 8)),
+        NamedSharding(mesh, P(None, "fleet", None, "model")))}
+    bt = {"x": jnp.ones((1, 3, n, 2, 4)), "y": jnp.ones((1, 3, n, 2, 8))}
+    w, live = jnp.full((1, 3, n, n), 1.0 / n), jnp.ones((1, 3, n), bool)
     lines = jax.jit(lambda p, w, l, b: train_on_traces(
         loss, p, w, l, b, params_batched=True, unroll=1, mesh=m)).lower(
             p, w, live, bt).compile().as_text().splitlines()
@@ -159,17 +165,25 @@ def _case(readings, case):
 
 @pytest.mark.parametrize("case", CASES)
 def test_exchange_mix_matches_dense_mix(readings, case):
-    """A random row-stochastic W: the exchange and the dense matmul agree
-    to fp32 round-off, and a leaf sharded over 'model' stays sharded."""
+    """A random row-stochastic W: the exchange and the one-device ``mix``
+    share one node-order combine, so at these node counts (all at or below
+    ``COMBINE_MAX_NODES``) the compiled two agree bit for bit, and with the
+    mix run op by op to fp32 round-off; a leaf sharded over 'model' stays
+    sharded."""
     r = _case(readings, case)
+    assert r["mix_exact"]
     assert r["mix_rel"] <= 1e-6
     assert "model" in r["wq_spec"] or case[1] == 1
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_exchange_mix_fused_update_matches_dense_step(readings, case):
-    """W X - eta G in one pass against ``_sgd(mix(X, W), G)``."""
-    assert _case(readings, case)["update_rel"] <= 1e-6
+    """W X - eta G in one pass against the one-device step's
+    ``_mix_update(X, W, G, eta)``: bit for bit compiled, and against
+    ``_sgd(mix(X, W), G)`` op by op to fp32 round-off."""
+    r = _case(readings, case)
+    assert r["update_exact"]
+    assert r["update_rel"] <= 1e-6
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -181,6 +195,7 @@ def test_exchange_mix_identity_w_returns_params_bit_for_bit(readings, case):
 def test_exchange_mix_keeps_embed_w_dead_rows_verbatim(readings, case):
     r = _case(readings, case)
     assert r["dead_rows_exact"]
+    assert r["dead_live_exact"]
     assert r["dead_live_rel"] <= 1e-6
 
 
@@ -201,9 +216,17 @@ def test_sharded_train_gathers_over_the_fleet_only_and_mixes_without_matmul(
         readings):
     """The compiled sharded training call gathers the node blocks over the
     fleet axis alone, so a leaf sharded over 'model' is never gathered
-    whole, and combines them without a W matmul. The dense path over the
-    same mesh does both (which shows that the searches find them)."""
+    whole, and combines them without a W matmul. The one-device mix past
+    ``COMBINE_MAX_NODES`` over the same mesh does both (which shows that
+    the searches find them)."""
     ex, dense = readings["exchange_hlo"], readings["dense_hlo"]
     assert ex["fleet_gathers"] > 0
     assert ex["model_gathers"] == 0 and ex["mix_matmuls"] == 0
     assert dense["model_gathers"] > 0 and dense["mix_matmuls"] > 0
+
+
+def test_one_device_combine_mixes_without_matmul(readings):
+    """At or below ``COMBINE_MAX_NODES`` the one-device mix compiles to the
+    node-order combine: no W matmul, and no leaf gathered whole."""
+    comb = readings["combine_hlo"]
+    assert comb["mix_matmuls"] == 0 and comb["model_gathers"] == 0

@@ -129,7 +129,7 @@ def test_spans_carry_their_arguments(profiled):
     assert scan == {"seed": 5, "n": 16, "rounds": 4,
                     "packets": -(-int(cfg.model_bits) // cfg.mac.packet_bits)}
     (*_, train, _), = _spans(host, "repro.train")
-    assert train == {"traces": 1, "rounds": 2, "nodes": 2, "mix": "dense",
+    assert train == {"traces": 1, "rounds": 2, "nodes": 2, "mix": "combine",
                      "experts_held": 0}
 
 
@@ -192,8 +192,9 @@ def test_step_scopes_reach_the_compiled_program(step, scopes):
 def test_train_span_says_exchange_on_a_fleet_mesh(tmp_path):
     """Over a mesh whose fleet axis shards the node axis the ``repro.train``
     span carries ``mix="exchange"``; over the same mesh with a node count
-    that does not divide the fleet, ``mix="dense"``. Four host devices, in
-    a subprocess: this process keeps seeing one."""
+    that does not divide the fleet, the one-device mix: ``mix="combine"``
+    up to ``COMBINE_MAX_NODES`` nodes, ``mix="dense"`` past it. Four host
+    devices, in a subprocess: this process keeps seeing one."""
     code = textwrap.dedent(f"""
         import glob, json
         import jax
@@ -205,7 +206,7 @@ def test_train_span_says_exchange_on_a_fleet_mesh(tmp_path):
 
         mesh = make_fleet_mesh(4, 1)
         jax.profiler.start_trace({str(tmp_path)!r})
-        for n in (4, 6):
+        for n in (4, 6, 10):
             cfg = get_scenario("static", n_nodes=n, seed=7)
             traces = stack_traces([precompute_trace(cfg, 2)])
             _, out = train_model_on_traces(LINEAR, [cfg], 2,
@@ -230,4 +231,4 @@ def test_train_span_says_exchange_on_a_fleet_mesh(tmp_path):
                          text=True, env=env, timeout=600)
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
     spans = json.loads(out.stdout.strip().splitlines()[-1])
-    assert spans == [[4, "exchange"], [6, "dense"]]
+    assert spans == [[4, "exchange"], [6, "combine"], [10, "dense"]]
